@@ -106,6 +106,8 @@ def _print_catalog(suites, file=None) -> None:
 
 def main() -> None:
     # imports here so `-m benchmarks.run fig2` doesn't pay for jax
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import benchmarks.paper_figures as pf
     from benchmarks.kernel_micro import engine_driver, kernel_micro
     from benchmarks.roofline import roofline_rows

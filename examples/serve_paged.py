@@ -52,7 +52,9 @@ def serve_lm(args) -> None:
 
     from repro import configs
     from repro.models import lm
+    from repro.runtime.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = configs.get_smoke_config(args.arch)
     params = lm.init_model(cfg, jax.random.PRNGKey(0))
     max_len = args.prompt_len + args.max_new

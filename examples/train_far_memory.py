@@ -19,10 +19,12 @@ from repro.data.pipeline import synthetic_batch
 from repro.models import lm
 from repro.optim import adamw
 from repro.runtime import steps as steps_mod
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.ft import StepMonitor, TrainSupervisor
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-moe-1b-a400m")
     ap.add_argument("--steps", type=int, default=60)

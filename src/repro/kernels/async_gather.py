@@ -19,6 +19,12 @@ K is sized by the latency-bandwidth product (``K * row_bytes >=
 HBM_latency * HBM_bw``), exactly the paper's "queue_length follows demand"
 rule. The grid is over index blocks so the scalar indices arrive via
 scalar prefetch (SMEM) before the block body runs.
+
+Layout: the table is held as ``[N, R, L]`` row tiles (``row_shape``). HBM
+tiles the last two dims in (8, 128) blocks, so a one-row slice of a plain
+``[N, D]`` table is not aligned to the tiling and the TPU compiler refuses
+the DMA. The leading dim of ``[N, R, L]`` is untiled, so one row is one
+aligned DMA at any dtype.
 """
 from __future__ import annotations
 
@@ -29,22 +35,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+
+
+def row_shape(d: int) -> tuple:
+    """Tile shape ``(R, L)`` of one row of width `d`: whole 128-lane tiles
+    when `d` allows it, else the row as a single tile."""
+    return (d // LANES, LANES) if d % LANES == 0 else (1, d)
+
 
 def _gather_kernel(idx_ref, table_ref, out_ref, slots, sems, *,
                    block_m: int, num_slots: int):
     """One grid step gathers `block_m` rows through a `num_slots`-deep ring.
 
-    idx_ref: SMEM [M] (scalar-prefetched); table_ref: ANY [N, D];
-    out_ref: VMEM [block_m, D]; slots: VMEM [num_slots, D]; sems: DMA [K].
+    idx_ref: SMEM [M] (scalar-prefetched); table_ref: ANY [N, R, L];
+    out_ref: VMEM [block_m, R, L]; slots: VMEM [num_slots, R, L];
+    sems: DMA [K].
     """
     base = pl.program_id(0) * block_m
 
     def dma(j, slot):
-        row = idx_ref[base + j]
-        return pltpu.make_async_copy(
-            table_ref.at[pl.ds(row, 1), :],
-            slots.at[pl.ds(slot, 1), :],
-            sems.at[slot])
+        return pltpu.make_async_copy(table_ref.at[idx_ref[base + j]],
+                                     slots.at[slot], sems.at[slot])
 
     # prime the ring: issue the first K aloads back-to-back (MLP!)
     def prime(j, _):
@@ -55,7 +67,7 @@ def _gather_kernel(idx_ref, table_ref, out_ref, slots, sems, *,
     def body(j, _):
         slot = j % num_slots
         dma(j, slot).wait()                    # getfin for this slot
-        out_ref[pl.ds(j, 1), :] = slots[pl.ds(slot, 1), :]
+        out_ref[j] = slots[slot]
 
         @pl.when(j + num_slots < block_m)
         def _():                               # reuse the freed slot
@@ -70,12 +82,13 @@ def _gather_kernel(idx_ref, table_ref, out_ref, slots, sems, *,
 def async_gather(table: jnp.ndarray, indices: jnp.ndarray,
                  block_m: int = 256, num_slots: int = 8,
                  interpret: bool = False) -> jnp.ndarray:
-    """out[i] = table[indices[i]]; table: [N, D], indices: [M] int32.
+    """out[i] = table[indices[i]]; table: [N, R, L] row tiles, indices: [M]
+    int32 -> [M, R, L].
 
     M must be a multiple of block_m (ops.py pads).
     """
     M = indices.shape[0]
-    N, D = table.shape
+    _, R, L = table.shape
     assert M % block_m == 0, (M, block_m)
     grid = (M // block_m,)
     kernel = functools.partial(_gather_kernel, block_m=block_m,
@@ -86,12 +99,12 @@ def async_gather(table: jnp.ndarray, indices: jnp.ndarray,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((block_m, D), lambda i, idx: (i, 0)),
+            out_specs=pl.BlockSpec((block_m, R, L), lambda i, idx: (i, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((num_slots, D), table.dtype),
+                pltpu.VMEM((num_slots, R, L), table.dtype),
                 pltpu.SemaphoreType.DMA((num_slots,)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((M, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, R, L), table.dtype),
         interpret=interpret,
     )(indices, table)

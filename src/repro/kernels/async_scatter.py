@@ -15,6 +15,9 @@ Per update j (paper Fig 4 + §5.1, on TPU):
 
 Loads are issued K ahead of use; stores drain lazily. The watermark (kept in
 SMEM) guarantees each store semaphore is waited exactly once.
+
+The table is held as ``[N, R, L]`` row tiles, as in ``async_gather``, so
+each row load and store is one DMA aligned to the HBM tiling.
 """
 from __future__ import annotations
 
@@ -34,15 +37,12 @@ def _scatter_kernel(idx_ref, upd_ref, table_in_ref, out_ref, slots_ld,
     del table_in_ref  # aliased with out_ref; all access goes through out_ref
 
     def load_dma(j):
-        row = idx_ref[base + j]
-        return pltpu.make_async_copy(out_ref.at[pl.ds(row, 1), :],
-                                     slots_ld.at[pl.ds(j % K, 1), :],
-                                     load_sems.at[j % K])
+        return pltpu.make_async_copy(out_ref.at[idx_ref[base + j]],
+                                     slots_ld.at[j % K], load_sems.at[j % K])
 
     def store_dma(j):
-        row = idx_ref[base + j]
-        return pltpu.make_async_copy(slots_st.at[pl.ds(j % K, 1), :],
-                                     out_ref.at[pl.ds(row, 1), :],
+        return pltpu.make_async_copy(slots_st.at[j % K],
+                                     out_ref.at[idx_ref[base + j]],
                                      store_sems.at[j % K])
 
     def drain_to(j_req):
@@ -90,11 +90,9 @@ def _scatter_kernel(idx_ref, upd_ref, table_in_ref, out_ref, slots_ld,
         def _():
             drain_to(j - K)
         if op == "add":
-            slots_st[pl.ds(slot, 1), :] = (slots_ld[pl.ds(slot, 1), :]
-                                           + upd_ref[pl.ds(j, 1), :])
+            slots_st[slot] = slots_ld[slot] + upd_ref[j]
         else:  # xor
-            slots_st[pl.ds(slot, 1), :] = (slots_ld[pl.ds(slot, 1), :]
-                                           ^ upd_ref[pl.ds(j, 1), :])
+            slots_st[slot] = slots_ld[slot] ^ upd_ref[j]
         store_dma(j).start()
 
         @pl.when(j + K < block_m)
@@ -112,11 +110,12 @@ def async_scatter(table: jnp.ndarray, indices: jnp.ndarray,
                   updates: jnp.ndarray, op: str = "add",
                   block_m: int = 256, num_slots: int = 8,
                   interpret: bool = False) -> jnp.ndarray:
-    """Returns table with rows RMW-updated: table[idx[j]] op= updates[j]."""
+    """Returns table with rows RMW-updated: table[idx[j]] op= updates[j].
+    table: [N, R, L] row tiles; updates: [M, R, L]."""
     M = indices.shape[0]
-    N, D = table.shape
+    _, R, L = table.shape
     assert M % block_m == 0, (M, block_m)
-    assert updates.shape == (M, D)
+    assert updates.shape == (M, R, L)
     grid = (M // block_m,)
     kernel = functools.partial(_scatter_kernel, block_m=block_m,
                                num_slots=num_slots, op=op)
@@ -126,19 +125,20 @@ def async_scatter(table: jnp.ndarray, indices: jnp.ndarray,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((block_m, D), lambda i, idx: (i, 0)),  # updates
+                pl.BlockSpec((block_m, R, L),                    # updates
+                             lambda i, idx: (i, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),               # table
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
-                pltpu.VMEM((num_slots, D), table.dtype),
-                pltpu.VMEM((num_slots, D), table.dtype),
+                pltpu.VMEM((num_slots, R, L), table.dtype),
+                pltpu.VMEM((num_slots, R, L), table.dtype),
                 pltpu.SemaphoreType.DMA((num_slots,)),
                 pltpu.SemaphoreType.DMA((num_slots,)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((N, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
     )(indices, updates, table)

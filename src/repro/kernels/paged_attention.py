@@ -8,8 +8,13 @@ grid pipeline keeps multiple page DMAs in flight while the MXU consumes the
 previous page (issue/complete decoupling). Pages past the sequence length
 are skipped via the scalar-prefetched `lengths`.
 
-Layout: q is grouped by KV head (GQA): [B, Hkv, G, D] so one grid step
-computes a whole query group against its single KV head page.
+Layout: the cache is read as ``[B, T, W]`` with ``W = Hkv * D`` (a reshape
+of ``[B, T, Hkv, D]``), so a page block ``(1, page, W)`` spans every KV head
+and meets the TPU's (8, 128) block rule for any head_dim. One grid step
+scores every query head against the whole page: query head ``i`` sits in
+the lanes of its KV head ``i // G`` and is zero elsewhere, so
+``q @ k_page.T`` is exactly the per-head logits, and ``p @ v_page`` holds
+head ``i``'s output in those same lanes, which the wrapper keeps.
 """
 from __future__ import annotations
 
@@ -27,8 +32,8 @@ NEG_INF = -1e30
 def _paged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, page: int, scale: float):
     b = pl.program_id(0)
-    pi = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    pi = pl.program_id(1)
+    np_ = pl.num_programs(1)
     seq_len = len_ref[b]
 
     @pl.when(pi == 0)
@@ -41,10 +46,10 @@ def _paged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(start < seq_len)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)            # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)         # [page, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        logits = (q @ k.T) * scale                     # [G, page]
+        q = q_ref[0].astype(jnp.float32)               # [Hq, W]
+        k = k_ref[0].astype(jnp.float32)               # [page, W]
+        v = v_ref[0].astype(jnp.float32)
+        logits = (q @ k.T) * scale                     # [Hq, page]
         pos = start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         logits = jnp.where(pos < seq_len, logits, NEG_INF)
         m_prev = m_ref[...]
@@ -52,13 +57,13 @@ def _paged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         p = jnp.exp(logits - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + p @ v
+        acc_ref[...] = acc_ref[...] * alpha + p @ v    # [Hq, W]
         m_ref[...] = m_new
 
     @pl.when(pi == np_ - 1)
     def _():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("page", "interpret"))
@@ -69,34 +74,33 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     out [B, Hq, D]."""
     B, Hq, D = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
-    G = Hq // Hkv
+    W = Hkv * D
     page = min(page, T)
     assert T % page == 0, (T, page)
     scale = 1.0 / math.sqrt(D)
-    qg = q.reshape(B, Hkv, G, D)
-    grid = (B, Hkv, T // page)
+    # query head i -> the lanes of its KV head i // G, zero elsewhere
+    own = jax.nn.one_hot(jnp.arange(Hq) // (Hq // Hkv), Hkv, dtype=q.dtype)
+    q_wide = (q[:, :, None, :] * own[None, :, :, None]).reshape(B, Hq, W)
     kernel = functools.partial(_paged_kernel, page=page, scale=scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(B, T // page),
             in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, pi, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, D),
-                             lambda b, h, pi, L: (b, pi, h, 0)),
-                pl.BlockSpec((1, page, 1, D),
-                             lambda b, h, pi, L: (b, pi, h, 0)),
+                pl.BlockSpec((1, Hq, W), lambda b, pi, L: (b, 0, 0)),
+                pl.BlockSpec((1, page, W), lambda b, pi, L: (b, pi, 0)),
+                pl.BlockSpec((1, page, W), lambda b, pi, L: (b, pi, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, pi, L: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, Hq, W), lambda b, pi, L: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
+                pltpu.VMEM((Hq, 1), jnp.float32),
+                pltpu.VMEM((Hq, 1), jnp.float32),
+                pltpu.VMEM((Hq, W), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, W), q.dtype),
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
-    return out.reshape(B, Hq, D)
+    )(lengths, q_wide, k_cache.reshape(B, T, W), v_cache.reshape(B, T, W))
+    # keep each query head's own lanes
+    return jnp.einsum("bhkd,hk->bhd", out.reshape(B, Hq, Hkv, D), own)

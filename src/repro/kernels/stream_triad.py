@@ -16,6 +16,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# 1024 rows of 128 lanes: a whole number of (8, 128) f32, (16, 128) bf16 and
+# (32, 128) int8 tiles, and 512 KiB of f32 per operand block.
+DEFAULT_BLOCK = 1024 * 128
+
 
 def _triad_kernel(s_ref, b_ref, c_ref, a_ref):
     a_ref[...] = b_ref[...] + s_ref[0] * c_ref[...]
@@ -23,7 +27,8 @@ def _triad_kernel(s_ref, b_ref, c_ref, a_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def stream_triad(b: jnp.ndarray, c: jnp.ndarray, s: float,
-                 block: int = 512, interpret: bool = False) -> jnp.ndarray:
+                 block: int = DEFAULT_BLOCK,
+                 interpret: bool = False) -> jnp.ndarray:
     """b, c: [N] (N % block == 0) -> a = b + s*c, streamed block by block."""
     (N,) = b.shape
     assert N % block == 0, (N, block)

@@ -23,6 +23,7 @@ from repro.optim import adamw  # noqa: E402
 from repro.runtime import hints  # noqa: E402
 from repro.runtime import sharding as shd  # noqa: E402
 from repro.runtime import steps as steps_mod  # noqa: E402
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 # --------------------------------------------------------------- HW constants
 PEAK_FLOPS = 197e12        # bf16 / chip (v5e-class)
@@ -217,6 +218,7 @@ def apply_tuning(tune) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
     ap.add_argument("--arch", default="all",
                     help="arch id or 'all'")

@@ -5,14 +5,9 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axes):
-    """`axis_types=` (and `jax.sharding.AxisType`) only exist on newer jax;
-    older releases default every axis to Auto, which is what we want."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,6 +16,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _make_mesh(shape, axes)
+
+
+def make_mesh(devices, shape):
+    """("data", "model") mesh of `shape` over the given devices."""
+    return _make_mesh(shape, ("data", "model"), devices=list(devices))
 
 
 def make_debug_mesh(devices: int = 8):

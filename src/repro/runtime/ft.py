@@ -53,7 +53,8 @@ class TrainSupervisor:
     by restoring the latest checkpoint and continuing — the restart path is
     the same code a cluster scheduler would re-enter after a node loss."""
 
-    def __init__(self, store: CheckpointStore, checkpoint_every: int = 50,
+    def __init__(self, store: Optional[CheckpointStore],
+                 checkpoint_every: int = 50,
                  monitor: Optional[StepMonitor] = None):
         self.store = store
         self.every = checkpoint_every
@@ -66,7 +67,8 @@ class TrainSupervisor:
             restore_fn: Optional[Callable] = None) -> Dict[str, Any]:
         """state: {"params", "opt_state", "step"}; step_fn(params, opt_state,
         batch) -> (params, opt_state, metrics); batch_fn(step) -> batch.
-        `fail_at` injects a failure once at that step (tests)."""
+        `fail_at` injects a failure once at that step (tests). Without a
+        store nothing is saved, and a failure restarts from step 0."""
         failed_once = False
         while state["step"] < total_steps:
             step = state["step"]
@@ -80,14 +82,15 @@ class TrainSupervisor:
                 self.monitor.record(step, time.monotonic() - t0)
                 state = {"params": params, "opt_state": opt_state,
                          "step": step + 1, "metrics": metrics}
-                if (step + 1) % self.every == 0:
+                if self.store is not None and (step + 1) % self.every == 0:
                     self.store.save(step + 1,
                                     {"params": state["params"],
                                      "opt_state": state["opt_state"]},
                                     extra={"step": step + 1})
             except SimulatedFailure:
                 self.restarts += 1
-                latest = self.store.latest_step()
+                latest = (None if self.store is None
+                          else self.store.latest_step())
                 if latest is None:
                     state = {**state, "step": 0}
                     continue
@@ -99,5 +102,6 @@ class TrainSupervisor:
                 state = {"params": restored["params"],
                          "opt_state": restored["opt_state"],
                          "step": extra["step"]}
-        self.store.wait()
+        if self.store is not None:
+            self.store.wait()
         return state

@@ -92,9 +92,11 @@ def make_serve_step(cfg: ModelConfig, par: ParallelConfig,
 # ------------------------------------------------------------ jit packaging
 def jit_train_step(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
                    opt_cfg: adamw.AdamWConfig, params: Params,
-                   opt_state: Params, shape: ShapeConfig,
-                   use_kernels: bool = False, moe_mode: str = "capacity"):
-    """jit with explicit in/out shardings + donation of params/opt_state."""
+                   shape: ShapeConfig, use_kernels: bool = False,
+                   moe_mode: str = "capacity"):
+    """jit with explicit param/opt-state shardings + donation of both.
+    `params` may be abstract (jax.eval_shape). Returns (step, param,
+    opt-state and batch shardings); batches arrive already placed."""
     p_sh = shd.params_shardings(cfg, par, mesh, params)
     o_sh = shd.opt_state_shardings(cfg, par, mesh, params)
     b_sh = shd.batch_shardings(cfg, par, mesh, shape)

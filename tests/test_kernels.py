@@ -1,5 +1,7 @@
 """Per-kernel correctness: shape/dtype sweeps against the ref.py oracles,
-all in interpret mode (CPU validates the TPU kernel bodies)."""
+all in interpret mode (CPU validates the TPU kernel bodies). The gather and
+scatter kernels take their tables as [N, R, L] row tiles (`ops.row_tiles`).
+tests/test_tpu_compile.py compiles the same kernels for a described TPU."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -28,8 +30,9 @@ def test_async_gather(n, d, m, bm, k, dtype):
     else:
         table = jnp.array(RNG.standard_normal((n, d)), dtype)
     idx = jnp.array(RNG.integers(0, n, m), jnp.int32)
-    out = async_gather(table, idx, block_m=bm, num_slots=k, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out),
+    out = async_gather(ops.row_tiles(table), idx, block_m=bm, num_slots=k,
+                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(out).reshape(m, d),
                                   np.asarray(ref.gather_ref(table, idx)))
 
 
@@ -44,10 +47,10 @@ def test_async_scatter_add(n, d, m, bm, k):
     table = jnp.array(RNG.standard_normal((n, d)), jnp.float32)
     idx = jnp.array(RNG.integers(0, n, m), jnp.int32)
     upd = jnp.array(RNG.standard_normal((m, d)), jnp.float32)
-    out = async_scatter(table, idx, upd, op="add", block_m=bm, num_slots=k,
-                        interpret=True)
+    out = async_scatter(ops.row_tiles(table), idx, ops.row_tiles(upd),
+                        op="add", block_m=bm, num_slots=k, interpret=True)
     np.testing.assert_allclose(
-        np.asarray(out),
+        np.asarray(out).reshape(n, d),
         np.asarray(ref.scatter_update_ref(table, idx, upd, "add")),
         atol=1e-4, rtol=1e-4)
 
@@ -58,10 +61,10 @@ def test_async_scatter_xor_gups():
     table = jnp.array(RNG.integers(0, 1 << 30, (n, d)), jnp.int32)
     idx = jnp.array(RNG.integers(0, n, m), jnp.int32)
     upd = jnp.array(RNG.integers(0, 1 << 30, (m, d)), jnp.int32)
-    out = async_scatter(table, idx, upd, op="xor", block_m=128, num_slots=8,
-                        interpret=True)
+    out = async_scatter(ops.row_tiles(table), idx, ops.row_tiles(upd),
+                        op="xor", block_m=128, num_slots=8, interpret=True)
     expect = ref.scatter_update_ref(table, idx, upd, "xor")
-    assert bool(jnp.all(out == expect))
+    assert bool(jnp.all(out.reshape(n, d) == expect))
 
 
 @pytest.mark.slow
@@ -75,10 +78,10 @@ def test_async_scatter_fuzz():
         table = jnp.array(rng.standard_normal((n, 32)), jnp.float32)
         idx = jnp.array(rng.integers(0, n, m), jnp.int32)
         upd = jnp.array(rng.standard_normal((m, 32)), jnp.float32)
-        out = async_scatter(table, idx, upd, op="add", block_m=bm,
-                            num_slots=k, interpret=True)
+        out = async_scatter(ops.row_tiles(table), idx, ops.row_tiles(upd),
+                            op="add", block_m=bm, num_slots=k, interpret=True)
         np.testing.assert_allclose(
-            np.asarray(out),
+            np.asarray(out).reshape(n, 32),
             np.asarray(ref.scatter_update_ref(table, idx, upd, "add")),
             atol=1e-4, rtol=1e-4)
 

@@ -56,3 +56,29 @@ def test_jit_sharding_smoke():
     spans = {len(leaf.sharding.device_set)
              for leaf in jax.tree_util.tree_leaves(params)}
     assert max(spans) > 1, spans
+
+
+def test_train_entry_sharded_matches_one_device():
+    """`launch.train.train()` (the path `chip_smoke.py --four-chips` takes):
+    state built under jit onto a 2x2 mesh, batches placed, no checkpoint
+    store; one step agrees with the same step on one device."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 (fake CPU) devices")
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import train
+
+    cfg = configs.get_smoke_config("granite-moe-1b-a400m")
+    shape = configs.ShapeConfig("smoke", 32, 8, "train")
+    par = configs.ParallelConfig(remat="full")
+    got = []
+    for devices, grid in ((jax.devices()[:4], (2, 2)),
+                          (jax.devices()[:1], (1, 1))):
+        state = train(cfg, shape, make_mesh(devices, grid), 1, par=par)
+        (metrics,) = state["history"]
+        got.append((float(metrics["loss"]), float(metrics["grad_norm"])))
+        spans = {len(leaf.sharding.device_set)
+                 for leaf in jax.tree_util.tree_leaves(state["params"])}
+        assert max(spans) == len(devices), spans
+    (l4, g4), (l1, g1) = got
+    assert abs(l4 - l1) <= 2e-3 * abs(l1), got
+    assert abs(g4 - g1) <= 5e-2 * abs(g1), got
