@@ -126,8 +126,9 @@ def kernel_phase(seed: int, vocab: int, d: int, rows: int, seq: int) -> None:
         a, r = a.astype(jnp.float32), r.astype(jnp.float32)
         return float(jnp.max(jnp.abs(a - r)) / jnp.max(jnp.abs(r)))
 
-    for hq, hkv, hd in ((16, 2, 128), (16, 8, 64)):
-        ks = jax.random.split(jax.random.PRNGKey(seed + hd), 4)
+    # qwen2.5-3b, granite-moe-1b-a400m, kimi-k2-1t-a32b
+    for hq, hkv, hd in ((16, 2, 128), (16, 8, 64), (64, 8, 128)):
+        ks = jax.random.split(jax.random.PRNGKey(seed + hq + hd), 4)
         q = jax.random.normal(ks[0], (8, hq, hd), jnp.bfloat16)
         kc = jax.random.normal(ks[1], (8, seq, hkv, hd), jnp.bfloat16)
         vc = jax.random.normal(ks[2], (8, seq, hkv, hd), jnp.bfloat16)

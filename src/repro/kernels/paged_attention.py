@@ -10,11 +10,11 @@ are skipped via the scalar-prefetched `lengths`.
 
 Layout: the cache is read as ``[B, T, W]`` with ``W = Hkv * D`` (a reshape
 of ``[B, T, Hkv, D]``), so a page block ``(1, page, W)`` spans every KV head
-and meets the TPU's (8, 128) block rule for any head_dim. One grid step
-scores every query head against the whole page: query head ``i`` sits in
-the lanes of its KV head ``i // G`` and is zero elsewhere, so
-``q @ k_page.T`` is exactly the per-head logits, and ``p @ v_page`` holds
-head ``i``'s output in those same lanes, which the wrapper keeps.
+and meets the TPU's (8, 128) block rule for any head_dim. Queries come as
+``[B, Hkv, G, D]``, the ``G = Hq / Hkv`` query heads of each KV head. One
+grid step walks the KV heads: head ``h`` scores its ``[G, D]`` queries
+against the page's lanes ``[h*D, (h+1)*D)``, so each matmul is as wide as
+one head, not the whole page.
 """
 from __future__ import annotations
 
@@ -35,6 +35,7 @@ def _paged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     pi = pl.program_id(1)
     np_ = pl.num_programs(1)
     seq_len = len_ref[b]
+    hkv, _, d = q_ref.shape[1:]
 
     @pl.when(pi == 0)
     def _():
@@ -46,19 +47,22 @@ def _paged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(start < seq_len)
     def _():
-        q = q_ref[0].astype(jnp.float32)               # [Hq, W]
-        k = k_ref[0].astype(jnp.float32)               # [page, W]
-        v = v_ref[0].astype(jnp.float32)
-        logits = (q @ k.T) * scale                     # [Hq, page]
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        logits = jnp.where(pos < seq_len, logits, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + p @ v    # [Hq, W]
-        m_ref[...] = m_new
+        for h in range(hkv):
+            lanes = pl.ds(h * d, d)
+            q = q_ref[0, h].astype(jnp.float32)            # [G, D]
+            k = k_ref[0, :, lanes].astype(jnp.float32)     # [page, D]
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ()))) * scale    # [G, page]
+            pos = start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            logits = jnp.where(pos < seq_len, logits, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + p @ v        # [G, D]
+            m_ref[h] = m_new
 
     @pl.when(pi == np_ - 1)
     def _():
@@ -71,36 +75,34 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, lengths: jnp.ndarray,
                     page: int = 512, interpret: bool = False) -> jnp.ndarray:
     """q: [B, Hq, D]; k_cache/v_cache: [B, T, Hkv, D]; lengths: [B] ->
-    out [B, Hq, D]."""
+    out [B, Hq, D]. Query head i reads KV head i // (Hq / Hkv)."""
     B, Hq, D = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
-    W = Hkv * D
+    G, W = Hq // Hkv, Hkv * D
     page = min(page, T)
     assert T % page == 0, (T, page)
     scale = 1.0 / math.sqrt(D)
-    # query head i -> the lanes of its KV head i // G, zero elsewhere
-    own = jax.nn.one_hot(jnp.arange(Hq) // (Hq // Hkv), Hkv, dtype=q.dtype)
-    q_wide = (q[:, :, None, :] * own[None, :, :, None]).reshape(B, Hq, W)
     kernel = functools.partial(_paged_kernel, page=page, scale=scale)
+    heads = pl.BlockSpec((1, Hkv, G, D), lambda b, pi, L: (b, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, T // page),
             in_specs=[
-                pl.BlockSpec((1, Hq, W), lambda b, pi, L: (b, 0, 0)),
+                heads,
                 pl.BlockSpec((1, page, W), lambda b, pi, L: (b, pi, 0)),
                 pl.BlockSpec((1, page, W), lambda b, pi, L: (b, pi, 0)),
             ],
-            out_specs=pl.BlockSpec((1, Hq, W), lambda b, pi, L: (b, 0, 0)),
+            out_specs=heads,
             scratch_shapes=[
-                pltpu.VMEM((Hq, 1), jnp.float32),
-                pltpu.VMEM((Hq, 1), jnp.float32),
-                pltpu.VMEM((Hq, W), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, W), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(lengths, q_wide, k_cache.reshape(B, T, W), v_cache.reshape(B, T, W))
-    # keep each query head's own lanes
-    return jnp.einsum("bhkd,hk->bhd", out.reshape(B, Hq, Hkv, D), own)
+    )(lengths, q.reshape(B, Hkv, G, D), k_cache.reshape(B, T, W),
+      v_cache.reshape(B, T, W))
+    return out.reshape(B, Hq, D)

@@ -102,7 +102,7 @@ def serve(cfg: ModelConfig, params, prompts: jnp.ndarray, max_new: int, *,
     t0 = None
     for i in range(1, max_new):
         if i == 2:              # step 1 was the warm-up
-            jax.block_until_ready(tok)
+            jax.block_until_ready((tok, lg, cur))
             t0 = time.perf_counter()
         if kv is not None:
             pages = [kv.fetch(j) for j in range(kv.num_layers)]
@@ -115,7 +115,8 @@ def serve(cfg: ModelConfig, params, prompts: jnp.ndarray, max_new: int, *,
         tok = next_token(lg, i, sub)
         out.append(tok)
         hist.append(lg[:, -1])
-    jax.block_until_ready(tok)
+    # with `feed`, tok does not depend on decode: wait on its outputs
+    jax.block_until_ready((tok, cur, hist))
     decode_s = None if t0 is None else time.perf_counter() - t0
     return ServeResult(tokens=jnp.concatenate(out, axis=1), logits=hist,
                        compiled={"prefill": prefill, "decode": decode},

@@ -31,15 +31,15 @@ from repro.runtime.ft import StepMonitor, TrainSupervisor
 
 
 def train(cfg: ModelConfig, shape: ShapeConfig, mesh, steps: int, *,
-          par: ParallelConfig, opt_cfg: Optional[adamw.AdamWConfig] = None,
-          seed: int = 0, store: Optional[CheckpointStore] = None,
+          par: ParallelConfig, seed: int = 0,
+          store: Optional[CheckpointStore] = None,
           checkpoint_every: int = 10, resume: bool = False,
           monitor: Optional[StepMonitor] = None
           ) -> Dict[str, Any]:
     """Run `steps` train steps on `mesh`. Returns the final state with
     `history`, the metrics of each step run here. With `store=None` no
     checkpoint is written or read."""
-    opt_cfg = opt_cfg or adamw.AdamWConfig(total_steps=steps)
+    opt_cfg = adamw.AdamWConfig(total_steps=steps)
     key = jax.random.PRNGKey(seed)
 
     def init_params(k):

@@ -133,6 +133,7 @@ def test_flash_attention_bf16():
     (3, 8, 2, 1024, 64, 256),
     (1, 4, 4, 512, 128, 512),    # MHA
     (2, 16, 2, 2048, 64, 512),   # deep GQA
+    (2, 10, 2, 512, 64, 256),    # odd group (5 query heads per KV)
 ])
 def test_paged_attention(b, hq, hkv, t, d, page):
     q = jnp.array(RNG.standard_normal((b, hq, d)), jnp.float32) * 0.3

@@ -52,7 +52,17 @@ def _compile(fn, *specs) -> str:
     return hlo
 
 
-@pytest.mark.parametrize("hq,hkv,d", [(16, 2, 128), (16, 8, 64), (4, 4, 128)])
+# every decoder config whose decode reaches the kernel, and plain MHA
+@pytest.mark.parametrize("hq,hkv,d", [
+    (16, 2, 128),     # qwen2.5-3b
+    (16, 8, 64),      # granite-moe-1b-a400m
+    (4, 4, 128),      # MHA
+    (64, 8, 128),     # kimi-k2-1t-a32b
+    (40, 8, 128),     # qwen2.5-32b
+    (28, 4, 128),     # qwen2-7b
+    (24, 8, 128),     # phi4-mini-3.8b
+    (12, 2, 128),     # qwen2-vl-2b
+])
 def test_paged_attention_compiles(one_chip, hq, hkv, d):
     B, T = 8, 1024
     q = jax.ShapeDtypeStruct((B, hq, d), jnp.bfloat16, sharding=one_chip)
