@@ -44,7 +44,7 @@ TRIAD_TOL = 1e-6
 # Attention kernels vs the f32 reference (HIGHEST precision) on the same
 # bf16 inputs, as max |err| over max |ref|: the kernels' in-kernel matmuls
 # run at the MXU's default precision and both outputs are rounded to bf16,
-# a few bf16 roundings (up to 5.4e-3 on a v5e). A wrong head, page or mask
+# a few bf16 roundings (up to 5.65e-3 on a v5e). A wrong head, page or mask
 # gives errors of order one.
 ATTN_TOL = 2e-2
 # Serving logits, kernel path vs jnp path, teacher-forced on the same
