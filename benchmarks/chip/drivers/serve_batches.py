@@ -233,6 +233,12 @@ def counts(state: State) -> Dict[str, int]:
     return {"attempted": reqs, "failed": 0}
 
 
+def programs(state: State) -> Dict[str, Any]:
+    """The compiled programs the window drives, for the trace reduction."""
+    return {"prefill_step": state.prefill, "serve_step": state.decode,
+            "sample": state.sample}
+
+
 def work(state: State) -> Dict[str, Any]:
     """Shapes of every call the window made, for the metric readers."""
     t = state.traffic

@@ -10,6 +10,11 @@ traffic mix (`traffic/<traffic>.json`, which names its driver,
 `drivers/<driver>.py`, and gives the driver's parameters); the limits that
 decide `correct` are in `limits/<cell>.json`; each per-layer metric is read
 by `metrics/<metric>.py` from the reduced trace and the cell's work counts.
+A driver may name its compiled programs (`programs(state)`): in a traced
+run each device operation is then joined to its HLO instruction, for its
+scope and class (`trace.attach_hlo`). A traced run also holds a host span
+"gc" open over each garbage collection, so that an idle gap under one
+reads `gc`.
 
 A run loads, compiles and warms up the cell's own shapes (set-up), measures
 for `--seconds` (or, with `--trace 1`, traces the traffic's `trace_*` share
@@ -34,12 +39,13 @@ import argparse  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
-from typing import Any, Dict  # noqa: E402
+from typing import Any, Callable, Dict  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -164,6 +170,47 @@ def _log(*parts) -> None:
     print(*parts, flush=True)
 
 
+def hlo_texts(driver, state) -> Dict[str, str]:
+    """HLO text of each compiled program the driver names, where it has
+    `programs(state) -> {name: Compiled}`."""
+    programs = getattr(driver, "programs", None)
+    texts = {}
+    for name, compiled in (programs(state) if programs else {}).items():
+        try:
+            texts[name] = compiled.as_text()
+        except NotImplementedError as e:
+            _log(f"hlo join: no text of {name}: {e}")
+    return texts
+
+
+def log_join(tr, modules) -> None:
+    """For each program joined, the share of its device time whose
+    operation found its instruction, the share without `op_name`, and its
+    seconds by class."""
+    for m in modules:
+        pat = re.escape(m)
+        joined, unnamed = trace_mod.join_shares(tr, pat)
+        split = ", ".join(f"{c} {tr.cls_s(c, pat)!r} s"
+                          for c in trace_mod.CLASSES)
+        _log(f"hlo join {m}: {100 * joined!r}% of device time joined, "
+             f"{100 * unnamed!r}% without op_name; {split}")
+
+
+def gc_spans() -> Callable[[str, dict], None]:
+    """A `gc.callbacks` entry that holds a host span named "gc" open over
+    each collection."""
+    open_ = []
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            open_.append(jax.profiler.TraceAnnotation("gc"))
+            open_[-1].__enter__()
+        elif open_:
+            open_.pop().__exit__(None, None, None)
+
+    return on_gc
+
+
 def run_cell(spec: SimpleNamespace, seed: int, seconds: float, traced: bool,
              devices: list, backend_s: float = 0.0) -> Dict[str, Any]:
     from repro import configs
@@ -195,8 +242,10 @@ def run_cell(spec: SimpleNamespace, seed: int, seconds: float, traced: bool,
     gc.collect()
     gc.freeze()
     trace_dir = tempfile.mkdtemp(prefix="chipbench_") if traced else None
+    on_gc = gc_spans()
     counter.armed = True
     if traced:
+        gc.callbacks.append(on_gc)
         jax.profiler.start_trace(trace_dir)
     try:
         with jax.profiler.TraceAnnotation("window"):
@@ -204,11 +253,13 @@ def run_cell(spec: SimpleNamespace, seed: int, seconds: float, traced: bool,
     finally:
         if traced:
             jax.profiler.stop_trace()
+            gc.callbacks.remove(on_gc)
         counter.armed = False
     peak = _peak_bytes(devices)
     e2e = driver.end_to_end(state, win)
     work = driver.work(state)
     n = driver.counts(state)
+    texts = hlo_texts(driver, state) if traced else {}
 
     t0 = time.perf_counter()
     checks = driver.check(state, spec.limits)
@@ -234,8 +285,13 @@ def run_cell(spec: SimpleNamespace, seed: int, seconds: float, traced: bool,
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:
         path = next(Path(trace_dir).rglob("*.xplane.pb"))
-        tr = trace_mod.load(str(path), span_names=driver.SPANS)
+        tr = trace_mod.load(str(path),
+                            span_names=tuple(driver.SPANS) + ("gc",))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log_join(tr, trace_mod.attach_hlo(tr, texts.values()))
+        gcs = [sp.end - sp.start for sp in tr.spans if sp.name == "gc"]
+        _log(f"gc: {len(gcs)} collections traced, longest "
+             f"{max(gcs, default=0.0) * 1e-6!r} ms")
         ctx = SimpleNamespace(trace=tr, work=work, config=spec.cfg_json,
                               peaks=peaks_mod.peaks_for(dev["kind"]),
                               log=_log)

@@ -1,13 +1,16 @@
 """The harness: every entry of BENCHMARK.json finds its files by name, a
 cell defined by new files alone runs, and the command refuses to run
 without a TPU or without the program."""
+import gc
 import json
 import os
 import shutil
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import jax
+import pytest
 
 from benchmarks.chip import run, trace
 from benchmarks.chip.tests import smoke
@@ -34,8 +37,6 @@ def test_every_entry_has_its_files():
 
 
 def test_metric_readers_return_nothing_on_an_empty_trace():
-    from types import SimpleNamespace
-
     from benchmarks.chip import peaks
 
     empty = trace.Trace(ops=[], spans=[], window=(0.0, 0.0), devices=[])
@@ -145,6 +146,84 @@ def test_traced_run_reads_the_trace(monkeypatch):
     assert result["device"]["busy_s"] == 0 and result["device"]["window_s"] > 0
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
     assert list(result)[-2:] == ["breakdown", "checks"]
+
+
+EXISTING = ("mfu.prefill", "mfu.decode", "paged_attention_roofline",
+            "flash_attention_roofline", "idle.serve")
+
+
+@pytest.mark.parametrize("hook", [True, False], ids=["programs", "none"])
+def test_traced_run_joins_the_programs_the_driver_names(monkeypatch, hook):
+    """A traced run on the CPU backend's plane: with the driver's
+    `programs()` every decode operation joins its instruction and
+    `data_movement.decode` is read; without it the run still ends and
+    leaves that metric out. Either way the existing readers and the
+    breakdown read what they read on the same trace without the join, and
+    no `gc` callback is left behind."""
+    from benchmarks.chip import peaks
+
+    smoke.use_smoke_program(monkeypatch)
+    monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
+    load, plain, seen = trace.load, [], {}
+
+    def cpu_load(path, span_names=()):
+        plain.append(load(path, span_names, device_plane=r"^/host:CPU$"))
+        return load(path, span_names, device_plane=r"^/host:CPU$")
+
+    monkeypatch.setattr(trace, "load", cpu_load)
+    driver = run.load_module(run.HERE / "drivers" / "serve_batches.py")
+    work = driver.work
+    monkeypatch.setattr(driver, "work",
+                        lambda st: seen.setdefault("work", work(st)))
+    if not hook:
+        monkeypatch.delattr(driver, "programs")
+    callbacks = list(gc.callbacks)
+    s = smoke.spec("qwen2.5-3b.decode_heavy")
+    result = run.run_cell(s, 2**31 + 17, 0.1, True, jax.devices()[:1])
+    assert gc.callbacks == callbacks
+    assert result["correct"] is True
+    metrics = result["metrics"]
+    if hook:
+        assert 0 < metrics["data_movement.decode"]["value"] < 100
+    else:
+        assert "data_movement.decode" not in metrics
+    ctx = SimpleNamespace(trace=plain[0], work=seen["work"],
+                          config=s.cfg_json, peaks=peaks.peaks_for("cpu"),
+                          log=lambda *a: None)
+    assert {"mfu.prefill", "mfu.decode", "idle.serve"} <= set(metrics)
+    for name in EXISTING:
+        reader = run.load_module(run.HERE / "metrics" / f"{name}.py")
+        want = reader.read(ctx)
+        assert metrics.get(name, {}).get("value") == want, name
+    assert result["breakdown"] == json.loads(json.dumps(
+        trace.breakdown(plain[0])))
+
+
+def test_untraced_run_reads_no_program_text(monkeypatch):
+    """A timed run neither asks the driver for its programs nor registers
+    a `gc` callback."""
+    smoke.use_smoke_program(monkeypatch)
+    driver = run.load_module(run.HERE / "drivers" / "serve_batches.py")
+
+    def refuse(state):
+        raise AssertionError("programs() called in a timed run")
+
+    monkeypatch.setattr(driver, "programs", refuse)
+    appended = []
+    monkeypatch.setattr(gc, "callbacks", _Watched(gc.callbacks, appended))
+    s = smoke.spec("qwen2.5-3b.decode_heavy")
+    result = run.run_cell(s, 2**31 + 19, 0.1, False, jax.devices()[:1])
+    assert result["correct"] is True and appended == []
+
+
+class _Watched(list):
+    def __init__(self, items, appended):
+        super().__init__(items)
+        self._appended = appended
+
+    def append(self, item):
+        self._appended.append(item)
+        super().append(item)
 
 
 def _command(cwd, env_extra=None):

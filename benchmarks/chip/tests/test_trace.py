@@ -1,6 +1,8 @@
 """The trace reduction on a small trace recorded on the CPU backend, and
 on hand-made intervals."""
+import gc
 import glob
+import os
 import time
 
 import jax
@@ -82,3 +84,134 @@ def test_recorded_trace_attributes_the_sleep_to_its_span(recorded):
     bd = T.breakdown(tr)
     assert bd["idle_gaps"][0][0] == "make_batch"
     assert len(bd["device_ops"]) <= 10 and bd["device_ops"][0][1] > 0
+
+
+# ------------------------------------------------------------- HLO join
+def _scoped_step(x, cache, row, i):
+    with jax.named_scope("mlp"):
+        h = jnp.tanh(x @ x)
+    with jax.named_scope("kv_cache"):
+        cache = jax.lax.dynamic_update_slice(cache, row, (i, jnp.uint32(0)))
+    with jax.named_scope("attn"):
+        s, _ = jax.lax.scan(lambda c, r: (c + jnp.exp(r).sum(), None),
+                            jnp.float32(0), cache)
+    return h.sum() + s, cache
+
+
+@pytest.fixture(scope="module")
+def scoped(tmp_path_factory):
+    """A program with three named scopes (a matmul, a cache update, a
+    scan), traced on the CPU backend and joined to its own HLO text."""
+    d = str(tmp_path_factory.mktemp("scoped"))
+    args = (jnp.ones((256, 256)), jnp.zeros((64, 256)), jnp.ones((1, 256)),
+            jnp.uint32(3))
+    compiled = jax.jit(_scoped_step).lower(*args).compile()
+    compiled(*args)[0].block_until_ready()
+    jax.profiler.start_trace(d)
+    compiled(*args)[0].block_until_ready()
+    jax.profiler.stop_trace()
+    path = glob.glob(d + "/**/*.xplane.pb", recursive=True)[0]
+    tr = T.load(path, device_plane=CPU_PLANE)
+    return tr, T.attach_hlo(tr, [compiled.as_text()])
+
+
+def test_join_gives_every_op_its_instruction(scoped):
+    tr, modules = scoped
+    assert modules == ["_scoped_step"]
+    joined, unnamed = T.join_shares(tr, "_scoped_step")
+    assert joined == 1.0 and 0 <= unnamed < 1
+    assert all(o.cls in T.CLASSES for o in tr.ops)
+
+
+def test_join_reads_scope_and_class(scoped):
+    tr, _ = scoped
+
+    def classes(scope):
+        return {o.cls for o in tr.ops if o.scope.endswith(scope)}
+
+    assert classes("/mlp/dot_general") == {"matmul"}
+    assert classes("/kv_cache/dynamic_update_slice") == {"movement"}
+    assert classes("/attn/while") == {"control"}
+    assert classes("/attn/while/body/closed_call/exp") == {"other"}
+
+
+def test_scope_and_class_sums_are_self_times(scoped):
+    tr, _ = scoped
+    ops = tr.select(module="_scoped_step")
+    total = sum(o.self_ns for o in ops) * 1e-9
+    assert sum(tr.cls_s(c, "_scoped_step") for c in T.CLASSES) == \
+        pytest.approx(total)
+    for scope in ("/mlp/", "/kv_cache/", "/attn/"):
+        want = sum(o.self_ns for o in ops if scope in o.scope) * 1e-9
+        assert 0 < tr.scope_s(scope, "_scoped_step") == pytest.approx(want)
+
+
+def test_join_leaves_ops_of_other_programs_alone(recorded):
+    """A program whose text was not given keeps no scope and no class, and
+    every existing query reads as before."""
+    before = [(o.name, o.module, o.start, o.end, o.self_ns)
+              for o in recorded.ops]
+    assert T.attach_hlo(recorded, []) == []
+    assert all(o.cls == "" and o.scope == "" for o in recorded.ops)
+    assert [(o.name, o.module, o.start, o.end, o.self_ns)
+            for o in recorded.ops] == before
+    assert T.join_shares(recorded, "lambda") == (0.0, 0.0)
+
+
+def test_classes_of_opcodes():
+    assert T.classify({"parameter", "dynamic-slice", "bitcast"}) == \
+        "movement"
+    assert T.classify({"parameter", "convolution", "add"}) == "matmul"
+    assert T.classify({"custom-call"}) == "kernel"
+    assert T.classify({"while"}) == "control"
+    assert T.classify({"parameter", "copy", "multiply"}) == "other"
+
+
+def test_parser_on_the_chips_decode_program():
+    """An excerpt of `serve_step` as compiled for a TPU v5e (qwen2.5-3b,
+    batch 32, cache 1024): the trace's module `jit_serve_step(<id>)` joins
+    the text's `jit_serve_step`, and each operation gets its class."""
+    path = os.path.join(os.path.dirname(__file__), "data",
+                        "serve_step_excerpt.hlo.txt")
+    with open(path) as f:
+        text = f.read()
+    want = {
+        "reshape.207": ("movement", "jit(paged_attention)/reshape"),
+        "dynamic-slice_bitcast_fusion.4": ("movement", "/while/body/squeeze"),
+        "bitcast_dynamic-update-slice_fusion.4":
+            ("movement", "/while/body/dynamic_update_slice"),
+        "fusion.125": ("matmul", "closed_call/dot_general"),
+        "paged_attention.5": ("kernel", "jit(paged_attention)/pallas_call"),
+        "while.2": ("control", "jit(serve_step)/while"),
+    }
+    ops = [T.Op(0, name, "jit_serve_step(4242)", i, i + 1)
+           for i, name in enumerate(want)]
+    ops.append(T.Op(0, "fusion.1", "jit_prefill_step(7)", 10, 11))
+    tr = T.Trace(ops=ops, spans=[], window=(0, 20), devices=[0])
+    assert T.attach_hlo(tr, [text]) == ["serve_step"]
+    for o in ops[:-1]:
+        cls, scope = want[o.name]
+        assert o.cls == cls and o.scope.endswith(scope), o
+    assert ops[-1].cls == "" and ops[-1].scope == ""
+
+
+def test_gc_spans_cover_each_collection(tmp_path):
+    """The harness's `gc.callbacks` entry writes a host span named "gc"
+    over a collection, and an instant inside it reads "gc"."""
+    from benchmarks.chip import run
+
+    on_gc = run.gc_spans()
+    jax.profiler.start_trace(str(tmp_path))
+    gc.callbacks.append(on_gc)
+    try:
+        with jax.profiler.TraceAnnotation("window"):
+            gc.collect()
+    finally:
+        gc.callbacks.remove(on_gc)
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)[0]
+    tr = T.load(path, span_names=("gc",), device_plane=CPU_PLANE)
+    spans = [s for s in tr.spans if s.name == "gc"]
+    assert spans and all(s.end >= s.start for s in spans)
+    s = spans[-1]
+    assert tr.span_at((s.start + s.end) / 2) == "gc"

@@ -6,6 +6,8 @@
     tr.module_s(r"serve_step")      # device time of one compiled program
     tr.idle_gaps()                  # gaps, each with the host span open
     breakdown(tr)                   # top operations and longest gaps
+    attach_hlo(tr, texts)           # each op's scope and class from the HLO
+    tr.scope_s(r"/kv_cache/"), tr.cls_s("movement", r"serve_step")
 
 Device operations are the events of a device plane's "XLA Ops" line (not
 "Async XLA Ops", whose copies and collectives are in flight while other
@@ -36,6 +38,8 @@ class Op:
     start: float
     end: float
     self_ns: float = 0.0
+    scope: str = ""     # the instruction's op_name metadata (`attach_hlo`)
+    cls: str = ""       # its class (`attach_hlo`); "" where not joined
 
 
 @dataclass
@@ -83,6 +87,16 @@ class Trace:
     def op_s(self, name: str, module: Optional[str] = None) -> float:
         """Summed self time of the matching operations, over all devices."""
         return sum(o.self_ns for o in self.select(name, module)) * 1e-9
+
+    def scope_s(self, pattern: str, module: Optional[str] = None) -> float:
+        """Summed self time of the operations whose scope matches."""
+        return sum(o.self_ns for o in self.select(module=module)
+                   if re.search(pattern, o.scope)) * 1e-9
+
+    def cls_s(self, cls: str, module: Optional[str] = None) -> float:
+        """Summed self time of the operations of one class."""
+        return sum(o.self_ns for o in self.select(module=module)
+                   if o.cls == cls) * 1e-9
 
     def op_count(self, name: str, module: Optional[str] = None) -> int:
         return len(self.select(name, module))
@@ -235,3 +249,131 @@ def _short(module: str) -> str:
     """`jit_serve_step(123)` -> `serve_step`."""
     name = re.sub(r"\(.*$", "", module)
     return name[4:] if name.startswith("jit_") else name
+
+
+# ----------------------------------------------------------- HLO join
+MATMUL = {"dot", "convolution"}
+KERNEL = {"custom-call"}
+MOVEMENT = {"copy", "bitcast", "reshape", "transpose", "slice",
+            "dynamic-slice", "dynamic-update-slice", "concatenate", "pad",
+            "broadcast", "convert"}
+PLUMBING = {"parameter", "constant", "tuple", "get-tuple-element"}
+CONTROL = {"while", "call", "conditional"}
+CLASSES = ("kernel", "matmul", "movement", "control", "other")
+
+_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+
+
+def _opcode(rest: str) -> str:
+    """The opcode of `<shape> <opcode>(...)`; a tuple shape is in
+    parentheses and may hold spaces."""
+    i = 0
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+    m = re.match(r"\S*\s+([\w\-]+)\(", rest[i:])
+    return m.group(1) if m else ""
+
+
+def classify(opcodes: Iterable[str]) -> str:
+    """The class of an instruction from its opcodes (see `attach_hlo`)."""
+    ops = set(opcodes)
+    if ops & MATMUL:
+        return "matmul"
+    if ops & KERNEL:
+        return "kernel"
+    if ops and ops <= MOVEMENT | PLUMBING:
+        return "movement"
+    if ops & CONTROL:
+        return "control"
+    return "other"
+
+
+def parse_hlo(text: str) -> Dict[str, Dict[str, Tuple[str, str]]]:
+    """HLO text (`Compiled.as_text()`, one or more modules) -> for each
+    module's short name, {instruction name: (op_name, class)}."""
+    parsed = []   # per module: {name: (opcode, op_name, calls)}, computations
+    comp: Optional[List[str]] = None
+    for line in text.splitlines():
+        m = _MODULE.match(line)
+        if m:
+            parsed.append((_short(m.group(1)), {}, {}))
+            comp = None
+        elif not parsed:
+            continue
+        elif comp is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                comp = parsed[-1][2].setdefault(m.group(1), [])
+        elif line.strip() == "}":
+            comp = None
+        else:
+            m = _INSTRUCTION.match(line)
+            if m:
+                rest = m.group(2)
+                op_name = _OP_NAME.search(rest)
+                parsed[-1][1][m.group(1)] = (
+                    _opcode(rest), op_name.group(1) if op_name else "",
+                    _CALLS.findall(rest))
+                comp.append(m.group(1))
+
+    out = {}
+    for module, insts, comps in parsed:
+        def opcodes(name: str) -> set:
+            opcode, _, calls = insts[name]
+            if opcode != "fusion":
+                return {opcode}
+            return set().union(*(opcodes(i) for c in calls
+                                 for i in comps.get(c, ())))
+        out[module] = {name: (op_name, classify(opcodes(name)))
+                       for name, (_, op_name, _) in insts.items()}
+    return out
+
+
+def attach_hlo(tr: Trace, texts: Iterable[str]) -> List[str]:
+    """Joins each device operation to its instruction in the compiled
+    programs' HLO text, by (program, instruction name), and sets its
+    `scope` (the instruction's `op_name` metadata, or "") and `cls`.
+    Returns the short names of the programs found in the texts.
+
+    The class of a fusion is that of the opcodes of its fused computation,
+    walked through the fusions nested in it; of any other instruction,
+    that of its own opcode. In this order:
+
+    - `matmul`: a `dot` or `convolution` among them;
+    - `kernel`: a `custom-call` (a Pallas kernel, or a helper of XLA's);
+    - `movement`: nothing but `copy`, `bitcast`, `reshape`, `transpose`,
+      `slice`, `dynamic-slice`, `dynamic-update-slice`, `concatenate`,
+      `pad`, `broadcast` and `convert`, with the plumbing opcodes
+      `parameter`, `constant`, `tuple` and `get-tuple-element`;
+    - `control`: a `while`, `call` or `conditional`;
+    - `other`: everything else.
+
+    An operation whose instruction is not found keeps `cls` "". Nothing
+    else of the trace changes."""
+    modules: Dict[str, Dict[str, Tuple[str, str]]] = {}
+    for text in texts:
+        modules.update(parse_hlo(text))
+    for o in tr.ops:
+        hit = modules.get(_short(o.module), {}).get(o.name)
+        if hit is not None:
+            o.scope, o.cls = hit
+    return list(modules)
+
+
+def join_shares(tr: Trace, module: str) -> Tuple[float, float]:
+    """Shares of a program's self time in the window whose operation found
+    its instruction, and whose instruction has no `op_name`."""
+    ops = tr.select(module=module)
+    total = sum(o.self_ns for o in ops)
+    if total <= 0:
+        return 0.0, 0.0
+    return (sum(o.self_ns for o in ops if o.cls) / total,
+            sum(o.self_ns for o in ops if o.cls and not o.scope) / total)
