@@ -1,11 +1,8 @@
-"""Kernel micro-benchmarks (interpret mode on CPU: correctness-shaped timing
-only; real numbers come from the TPU target). Reports us/call plus the
-derived achieved-bytes/flops so the TPU roofline expectation is visible.
-
-Also hosts the timed-engine *driver throughput* micro (`engine_driver`):
-host-side requests retired per second through the scalar oracle vs the
-vectorized batched engine, which is what bounds how large a latency x
-queue-depth paper sweep is tractable on CPU."""
+"""The timed-engine *driver throughput* micro (`engine_driver`): host-side
+requests retired per second through the scalar oracle vs the vectorized
+batched engine, which is what bounds how large a latency x queue-depth
+paper sweep is tractable on CPU. The kernels are measured on the chip by
+`benchmarks/chip/`."""
 from __future__ import annotations
 
 import time
@@ -156,57 +153,3 @@ def engine_driver(n_requests: int = 100_000, smoke: bool = False) -> List[Row]:
                      f"{_entry_counters(f_st)}"))
     return rows
 
-
-def _time(fn, *args, reps=3) -> float:
-    import jax
-    fn(*args)  # warm
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / reps * 1e6
-
-
-def kernel_micro() -> List[Row]:
-    # jax only needed for the Pallas kernel rows, not the engine driver
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(0)
-    rows: List[Row] = []
-    # GUPS-gather (the paper's flagship random-access pattern)
-    table = jnp.array(rng.standard_normal((4096, 128)), jnp.float32)
-    idx = jnp.array(rng.integers(0, 4096, 1024), jnp.int32)
-    us = _time(lambda: ops.gather(table, idx, block_m=256, num_slots=8))
-    moved = 1024 * 128 * 4 * 2
-    rows.append(("kernel/async_gather_1k_rows", us,
-                 f"bytes={moved},slots=8"))
-    # GUPS-update
-    upd = jnp.array(rng.standard_normal((1024, 128)), jnp.float32)
-    us = _time(lambda: ops.scatter_update(table, idx, upd, block_m=256,
-                                          num_slots=8))
-    rows.append(("kernel/async_scatter_1k_rows", us,
-                 f"bytes={moved * 2},slots=8"))
-    # STREAM triad
-    b = jnp.array(rng.standard_normal(1 << 16), jnp.float32)
-    c = jnp.array(rng.standard_normal(1 << 16), jnp.float32)
-    us = _time(lambda: ops.triad(b, c, 3.0, block=512))
-    rows.append(("kernel/stream_triad_64k", us,
-                 f"bytes={3 * (1 << 16) * 4}"))
-    # flash attention prefill block
-    q = jnp.array(rng.standard_normal((1, 512, 8, 64)), jnp.bfloat16)
-    k = jnp.array(rng.standard_normal((1, 512, 2, 64)), jnp.bfloat16)
-    v = jnp.array(rng.standard_normal((1, 512, 2, 64)), jnp.bfloat16)
-    us = _time(lambda: ops.flash_attention(q, k, v, causal=True))
-    flops = 4 * 512 * 512 * 8 * 64
-    rows.append(("kernel/flash_attention_512", us, f"flops={flops}"))
-    # paged decode attention
-    q1 = jnp.array(rng.standard_normal((4, 8, 64)), jnp.float32)
-    kc = jnp.array(rng.standard_normal((4, 2048, 2, 64)), jnp.float32)
-    vc = jnp.array(rng.standard_normal((4, 2048, 2, 64)), jnp.float32)
-    lens = jnp.array([2048, 1024, 512, 2048], jnp.int32)
-    us = _time(lambda: ops.paged_attention(q1, kc, vc, lens, page=512))
-    rows.append(("kernel/paged_attention_2k_kv", us,
-                 f"kv_bytes={4 * 2048 * 2 * 64 * 4 * 2}"))
-    return rows

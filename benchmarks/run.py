@@ -1,13 +1,13 @@
-"""Benchmark harness: one function per paper table/figure + kernel micro +
-engine-driver throughput + roofline. Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness: one function per paper table/figure + engine-driver
+throughput. Prints ``name,us_per_call,derived`` CSV. The kernels and the
+step programs are measured on the chip by `benchmarks/chip/`.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--engine scalar|batched]
                                                [--vector] [--sanitize]
                                                [--smoke] [--list]
                                                [--json PATH]
                                                [--profile PATH] [figure ...]
-(no args -> everything; roofline rows require results/dryrun.jsonl;
-`--list` prints the sweep names and the registered workloads with their
+(no args -> everything; `--list` prints the sweep names and the registered workloads with their
 declared capabilities, then exits).
 `--engine` picks the timed-engine implementation behind the AMU configs:
 "batched" (default; vectorized, fast sweeps) or "scalar" (per-event oracle).
@@ -107,8 +107,7 @@ def _print_catalog(suites, file=None) -> None:
 def main() -> None:
     # imports here so `-m benchmarks.run fig2` doesn't pay for jax
     import benchmarks.paper_figures as pf
-    from benchmarks.kernel_micro import engine_driver, kernel_micro
-    from benchmarks.roofline import roofline_rows
+    from benchmarks.kernel_micro import engine_driver
 
     args = sys.argv[1:]
     if "--engine" in args:
@@ -123,8 +122,8 @@ def main() -> None:
         pf.AMU = pf.AMU.derive(vector=True)
         args.remove("--vector")
     if "--sanitize" in args:
-        # env var first: suites that build their own AmuConfig (kernel
-        # micro-benchmarks) pick the default up from AMU_SANITIZE
+        # env var first: suites that build their own AmuConfig (the
+        # engine-driver micro) pick the default up from AMU_SANITIZE
         os.environ["AMU_SANITIZE"] = "1"
         pf.AMU = pf.AMU.derive(sanitize=True)
         args.remove("--sanitize")
@@ -154,12 +153,10 @@ def main() -> None:
         profiler.enable()
 
     suites = dict(pf.ALL_FIGURES)
-    suites["kernels"] = kernel_micro
     suites["engine"] = lambda: engine_driver(smoke=smoke)
     suites["serve"] = lambda: pf.serve_latency(smoke=smoke)
     suites["faults"] = lambda: pf.fault_tolerance(smoke=smoke)
     suites["rack"] = lambda: pf.rack_scaling(smoke=smoke)
-    suites["roofline"] = roofline_rows
 
     if "--list" in args:
         _print_catalog(suites)
@@ -173,9 +170,6 @@ def main() -> None:
         wanted = list(always) + [a for a in args if a not in always]
     else:
         wanted = args or list(suites)
-    if "kernels" in wanted:     # the one suite that compiles with jax
-        from repro.runtime.compile_cache import enable_compile_cache
-        enable_compile_cache()
     collected = []
     print("name,us_per_call,derived")
     for name in wanted:

@@ -2,7 +2,10 @@
 backend dispatch (interpret mode on the CPU, compiled on a TPU, an error on
 any other backend).
 
-The model layer (`repro.models.blocks`) calls these when `use_kernels=True`;
+The attention wrappers' bodies run under `jax.named_scope(<kernel>)`
+(`repro.scopes`), so the compiled step programs name the kernel's glue and
+call by it. The model layer (`repro.models.blocks`) calls these when
+`use_kernels=True`;
 the multi-pod dry-run lowers the pure-jnp reference path instead (Pallas
 interpret mode does not compose with SPMD partitioning on the CPU backend),
 so the kernels are validated standalone against ref.py.
@@ -12,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.kernels import ref
 from repro.kernels.async_gather import async_gather as _gather, row_shape
 from repro.kernels.async_scatter import async_scatter as _scatter
@@ -87,29 +91,31 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """Model-layer layout: q [B, S, Hq, D], k/v [B, S, Hkv, D] ->
     [B, S, Hq, D]. Pads S to the block size (extra keys are masked by
     causality; extra query rows are sliced off)."""
-    Bq = jnp.swapaxes(q, 1, 2)          # [B, Hq, S, D]
-    Bk = jnp.swapaxes(k, 1, 2)
-    Bv = jnp.swapaxes(v, 1, 2)
-    S = Bq.shape[2]
-    blk = min(block_q, block_k)
-    Bq, _ = _pad_to(Bq, 2, blk)
-    Bk, _ = _pad_to(Bk, 2, blk)
-    Bv, _ = _pad_to(Bv, 2, blk)
-    out = _flash(Bq, Bk, Bv, causal=causal, window=window,
-                 block_q=min(block_q, Bq.shape[2]),
-                 block_k=min(block_k, Bk.shape[2]),
-                 interpret=_interpret())
-    return jnp.swapaxes(out[:, :, :S], 1, 2)
+    with jax.named_scope(scopes.FLASH_ATTENTION):
+        Bq = jnp.swapaxes(q, 1, 2)          # [B, Hq, S, D]
+        Bk = jnp.swapaxes(k, 1, 2)
+        Bv = jnp.swapaxes(v, 1, 2)
+        S = Bq.shape[2]
+        blk = min(block_q, block_k)
+        Bq, _ = _pad_to(Bq, 2, blk)
+        Bk, _ = _pad_to(Bk, 2, blk)
+        Bv, _ = _pad_to(Bv, 2, blk)
+        out = _flash(Bq, Bk, Bv, causal=causal, window=window,
+                     block_q=min(block_q, Bq.shape[2]),
+                     block_k=min(block_k, Bk.shape[2]),
+                     interpret=_interpret())
+        return jnp.swapaxes(out[:, :, :S], 1, 2)
 
 
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, lengths: jnp.ndarray,
                     page: int = 512) -> jnp.ndarray:
     """Decode attention. q: [B, Hq, D]; caches [B, T, Hkv, D]; lengths [B]."""
-    kp, _ = _pad_to(k_cache, 1, page)
-    vp, _ = _pad_to(v_cache, 1, page)
-    return _paged(q, kp, vp, lengths.astype(jnp.int32), page=page,
-                  interpret=_interpret())
+    with jax.named_scope(scopes.PAGED_ATTENTION):
+        kp, _ = _pad_to(k_cache, 1, page)
+        vp, _ = _pad_to(v_cache, 1, page)
+        return _paged(q, kp, vp, lengths.astype(jnp.int32), page=page,
+                      interpret=_interpret())
 
 
 __all__ = ["gather", "scatter_update", "triad", "flash_attention",

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 NEG_INF = -1e30
 
 
@@ -84,6 +86,10 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scale = 1.0 / math.sqrt(D)
     kernel = functools.partial(_paged_kernel, page=page, scale=scale)
     heads = pl.BlockSpec((1, Hkv, G, D), lambda b, pi, L: (b, 0, 0, 0))
+    q_heads = q.reshape(B, Hkv, G, D)
+    with jax.named_scope(scopes.KV_RELAYOUT):   # [B, T, Hkv, D] -> [B, T, W]
+        k_pages = k_cache.reshape(B, T, W)
+        v_pages = v_cache.reshape(B, T, W)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -103,6 +109,5 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(lengths, q.reshape(B, Hkv, G, D), k_cache.reshape(B, T, W),
-      v_cache.reshape(B, T, W))
+    )(lengths, q_heads, k_pages, v_pages)
     return out.reshape(B, Hq, D)

@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.runtime import hints
 
@@ -200,16 +201,17 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     B, S, d = x.shape
     hd = cfg.resolved_head_dim
     Hq, Hkv = cfg.num_heads, cfg.num_kv_heads
-    q = x @ p["wq"]
-    k = x @ p["wk"]
-    v = x @ p["wv"]
-    if cfg.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(B, S, Hq, hd)
-    k = k.reshape(B, S, Hkv, hd)
-    v = v.reshape(B, S, Hkv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
-    k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
+    with jax.named_scope(scopes.QKV):
+        q = x @ p["wq"]
+        k = x @ p["wk"]
+        v = x @ p["wv"]
+        if cfg.qkv_bias:
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+        q = q.reshape(B, S, Hq, hd)
+        k = k.reshape(B, S, Hkv, hd)
+        v = v.reshape(B, S, Hkv, hd)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
     # sharding hints: shard heads over "model" when divisible, else fall
     # back to sharding the sequence (keeps 28/40-head configs from
     # replicating S x S logits on every chip)
@@ -250,16 +252,15 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         T = ck.shape[1]
         # scatter the new tokens at cache_len (decode: S == 1 typically)
         idx = (cache_len[:, None] + jnp.arange(S)[None, :])  # [B, S]
-        bidx = jnp.arange(B)[:, None]
-        ck = ck.at[bidx, idx].set(k.astype(ck.dtype))
-        cv = cv.at[bidx, idx].set(v.astype(cv.dtype))
+        with jax.named_scope(scopes.KV_CACHE_WRITE):
+            bidx = jnp.arange(B)[:, None]
+            ck = ck.at[bidx, idx].set(k.astype(ck.dtype))
+            cv = cv.at[bidx, idx].set(v.astype(cv.dtype))
         new_cache = (ck, cv)
         if use_kernels and S == 1 and window == 0:
             from repro.kernels import ops as kops
             out = kops.paged_attention(q[:, 0], ck, cv, cache_len + S)
-            out = out[:, None]
-            out = out.reshape(B, S, Hq * hd) @ p["wo"]
-            return out, new_cache
+            return _out_proj(p, out[:, None]), new_cache
         k_all, v_all = ck, cv
         # valid-key mask (+ causal within the new tokens + window)
         k_pos = jnp.arange(T)[None, None, :]                   # [1,1,T]
@@ -276,15 +277,13 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         if use_kernels and cfg.causal and S >= 128:
             from repro.kernels import ops as kops
             out = kops.flash_attention(q, k, v, causal=True, window=window)
-            out = out.reshape(B, S, Hq * hd) @ p["wo"]
-            return out, new_cache
+            return _out_proj(p, out), new_cache
         if S >= ATTN_CONFIG["chunk_threshold"]:
             rep = Hq // Hkv
             out = _chunked_attention(q, jnp.repeat(k, rep, axis=2),
                                      jnp.repeat(v, rep, axis=2),
                                      cfg.causal, window)
-            out = out.reshape(B, S, Hq * hd) @ p["wo"]
-            return out, new_cache
+            return _out_proj(p, out), new_cache
         mask = _attn_mask(S, T, cfg.causal, window, 0)[None, None]
 
     # grouped heads: repeat kv
@@ -302,8 +301,13 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
             :, :, :, :group - pad_per_group]
         Hq = Hkv * (group - pad_per_group)
         out = out.reshape(B, S, Hq, hd)
-    out = out.reshape(B, S, Hq * hd) @ p["wo"]
-    return out, new_cache
+    return _out_proj(p, out), new_cache
+
+
+def _out_proj(p: Params, out: jnp.ndarray) -> jnp.ndarray:
+    """Heads [B, S, H, D] -> [B, S, d] through `wo`."""
+    with jax.named_scope(scopes.OUT_PROJ):
+        return out.reshape(out.shape[:2] + (-1,)) @ p["wo"]
 
 
 def ring_attention_step(cfg: ModelConfig, p: Params, x: jnp.ndarray,
@@ -320,20 +324,22 @@ def ring_attention_step(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     hd = cfg.resolved_head_dim
     Hq, Hkv = cfg.num_heads, cfg.num_kv_heads
     Wn = ck.shape[1]
-    q = x @ p["wq"]
-    k = x @ p["wk"]
-    v = x @ p["wv"]
-    if cfg.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q.reshape(Bsz, 1, Hq, hd), positions, cfg.rope_theta,
-                   cfg.mrope_sections)
-    k = apply_rope(k.reshape(Bsz, 1, Hkv, hd), positions, cfg.rope_theta,
-                   cfg.mrope_sections)
-    v = v.reshape(Bsz, 1, Hkv, hd)
-    slot = cache_len % Wn                                   # [B]
-    bidx = jnp.arange(Bsz)
-    ck = ck.at[bidx, slot].set(k[:, 0].astype(ck.dtype))
-    cv = cv.at[bidx, slot].set(v[:, 0].astype(cv.dtype))
+    with jax.named_scope(scopes.QKV):
+        q = x @ p["wq"]
+        k = x @ p["wk"]
+        v = x @ p["wv"]
+        if cfg.qkv_bias:
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+        q = apply_rope(q.reshape(Bsz, 1, Hq, hd), positions, cfg.rope_theta,
+                       cfg.mrope_sections)
+        k = apply_rope(k.reshape(Bsz, 1, Hkv, hd), positions, cfg.rope_theta,
+                       cfg.mrope_sections)
+        v = v.reshape(Bsz, 1, Hkv, hd)
+    with jax.named_scope(scopes.KV_CACHE_WRITE):
+        slot = cache_len % Wn                               # [B]
+        bidx = jnp.arange(Bsz)
+        ck = ck.at[bidx, slot].set(k[:, 0].astype(ck.dtype))
+        cv = cv.at[bidx, slot].set(v[:, 0].astype(cv.dtype))
     valid = jnp.arange(Wn)[None, :] <= jnp.minimum(cache_len, Wn - 1)[:, None]
     rep = Hq // Hkv
     k_all = jnp.repeat(ck, rep, axis=2)
@@ -343,8 +349,7 @@ def ring_attention_step(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                        -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     out = jnp.einsum("bhst,bthd->bshd", probs, v_all)
-    out = out.reshape(Bsz, 1, Hq * hd) @ p["wo"]
-    return out, (ck, cv)
+    return _out_proj(p, out), (ck, cv)
 
 
 # ---------------------------------------------------------------------- MLP

@@ -19,6 +19,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import (BLOCK_FULL, BLOCK_LOCAL, BLOCK_RGLRU,
                                 BLOCK_RWKV6, ModelConfig)
 from repro.models import blocks as B
@@ -125,6 +126,60 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 # =================================================================== layers
+# Every layer of the step programs is named with `jax.named_scope`, which
+# sets the compiled instructions' `op_name` metadata and nothing else; the
+# names and their nesting are in `repro.scopes`.
+
+
+def _apply_mixer(cfg: ModelConfig, kind: str, p: Params, h: jnp.ndarray,
+                 positions: jnp.ndarray, cache: Optional[Params],
+                 cache_len: Optional[jnp.ndarray], use_kernels: bool
+                 ) -> Tuple[jnp.ndarray, Optional[Params]]:
+    if kind == BLOCK_RGLRU:
+        return R.apply_rglru(cfg, p, h, cache)
+    if kind == BLOCK_RWKV6:
+        return W.apply_rwkv6(cfg, p, h, cache)
+    window = cfg.window_size if kind == BLOCK_LOCAL else 0
+    if cache is None:
+        out, _ = B.attention(cfg, p, h, positions, window=window,
+                             use_kernels=use_kernels)
+        return out, None
+    if kind == BLOCK_LOCAL and h.shape[1] == 1:
+        # decode through the ring-buffered window cache
+        out, nc = B.ring_attention_step(cfg, p, h, positions, cache["k"],
+                                        cache["v"], cache_len)
+    elif kind == BLOCK_LOCAL:
+        # windowed prefill; ring-fill the cache with the last W
+        # tokens (slot = absolute position mod W)
+        out, kv = B.attention(cfg, p, h, positions, window=window,
+                              use_kernels=use_kernels, return_kv=True)
+        Wn = cache["k"].shape[1]
+        S = h.shape[1]
+        take = min(Wn, S)
+        with jax.named_scope(scopes.KV_CACHE_WRITE):
+            slots = (jnp.arange(S - take, S)) % Wn
+            nc = (cache["k"].at[:, slots].set(
+                      kv[0][:, -take:].astype(cache["k"].dtype)),
+                  cache["v"].at[:, slots].set(
+                      kv[1][:, -take:].astype(cache["v"].dtype)))
+    elif h.shape[1] > 1:
+        # full-attention prefill: run self-attention (chunked for
+        # long S) and bulk-fill the cache prefix — avoids the
+        # [S, T_max] masked-cache path entirely.
+        out, kv = B.attention(cfg, p, h, positions, window=window,
+                              use_kernels=use_kernels, return_kv=True)
+        S = h.shape[1]
+        with jax.named_scope(scopes.KV_CACHE_WRITE):
+            nc = (cache["k"].at[:, :S].set(kv[0].astype(cache["k"].dtype)),
+                  cache["v"].at[:, :S].set(kv[1].astype(cache["v"].dtype)))
+    else:
+        out, nc = B.attention(cfg, p, h, positions,
+                              kv_cache=(cache["k"], cache["v"]),
+                              cache_len=cache_len,
+                              window=window, use_kernels=use_kernels)
+    return out, {"k": nc[0], "v": nc[1]}
+
+
 def _apply_layer(cfg: ModelConfig, kind: str, p: Params, x: jnp.ndarray,
                  positions: jnp.ndarray, cache: Optional[Params],
                  cache_len: Optional[jnp.ndarray], use_kernels: bool,
@@ -136,63 +191,19 @@ def _apply_layer(cfg: ModelConfig, kind: str, p: Params, x: jnp.ndarray,
         # and reduce-scatters the outputs (halves activation-collective
         # volume vs all-reduce and shards the residual/norm memory).
         x = hints.constrain(x, hints.batch_spec_axes(), "model", None)
-    h = B.apply_norm(cfg, p["norm1"], x)
-    new_cache = None
-    window = cfg.window_size if kind == BLOCK_LOCAL else 0
-    if kind in (BLOCK_FULL, BLOCK_LOCAL):
-        if cache is not None:
-            if kind == BLOCK_LOCAL and h.shape[1] == 1:
-                # decode through the ring-buffered window cache
-                out, nc = B.ring_attention_step(
-                    cfg, p["mix"], h, positions, cache["k"], cache["v"],
-                    cache_len)
-            elif kind == BLOCK_LOCAL:
-                # windowed prefill; ring-fill the cache with the last W
-                # tokens (slot = absolute position mod W)
-                out, kv = B.attention(cfg, p["mix"], h, positions,
-                                      window=window, use_kernels=use_kernels,
-                                      return_kv=True)
-                Wn = cache["k"].shape[1]
-                S = h.shape[1]
-                take = min(Wn, S)
-                slots = (jnp.arange(S - take, S)) % Wn
-                nc = (cache["k"].at[:, slots].set(
-                          kv[0][:, -take:].astype(cache["k"].dtype)),
-                      cache["v"].at[:, slots].set(
-                          kv[1][:, -take:].astype(cache["v"].dtype)))
-            elif h.shape[1] > 1:
-                # full-attention prefill: run self-attention (chunked for
-                # long S) and bulk-fill the cache prefix — avoids the
-                # [S, T_max] masked-cache path entirely.
-                out, kv = B.attention(cfg, p["mix"], h, positions,
-                                      window=window, use_kernels=use_kernels,
-                                      return_kv=True)
-                S = h.shape[1]
-                nc = (cache["k"].at[:, :S].set(kv[0].astype(cache["k"].dtype)),
-                      cache["v"].at[:, :S].set(kv[1].astype(cache["v"].dtype)))
-            else:
-                out, nc = B.attention(cfg, p["mix"], h, positions,
-                                      kv_cache=(cache["k"], cache["v"]),
-                                      cache_len=cache_len,
-                                      window=window, use_kernels=use_kernels)
-            new_cache = {"k": nc[0], "v": nc[1]}
+    with jax.named_scope(scopes.MIXER[kind]):
+        h = B.apply_norm(cfg, p["norm1"], x)
+        out, new_cache = _apply_mixer(cfg, kind, p["mix"], h, positions,
+                                      cache, cache_len, use_kernels)
+        x = x + out
+    aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope(scopes.MLP if cfg.moe is None else scopes.MOE):
+        h2 = B.apply_norm(cfg, p["norm2"], x)
+        if cfg.moe is not None:
+            ffn_out, aux = M.apply_moe(cfg, p["ffn"], h2, mode=moe_mode)
         else:
-            out, _ = B.attention(cfg, p["mix"], h, positions, window=window,
-                                 use_kernels=use_kernels)
-        aux = jnp.zeros((), jnp.float32)
-    elif kind == BLOCK_RGLRU:
-        out, new_cache = R.apply_rglru(cfg, p["mix"], h, cache)
-        aux = jnp.zeros((), jnp.float32)
-    else:  # rwkv6
-        out, new_cache = W.apply_rwkv6(cfg, p["mix"], h, cache)
-        aux = jnp.zeros((), jnp.float32)
-    x = x + out
-    h2 = B.apply_norm(cfg, p["norm2"], x)
-    if cfg.moe is not None:
-        ffn_out, aux = M.apply_moe(cfg, p["ffn"], h2, mode=moe_mode)
-    else:
-        ffn_out = B.apply_mlp(cfg, p["ffn"], h2)
-    return x + ffn_out, new_cache, aux
+            ffn_out = B.apply_mlp(cfg, p["ffn"], h2)
+        return x + ffn_out, new_cache, aux
 
 
 # ================================================================== forward
@@ -232,9 +243,10 @@ def forward_blocks(cfg: ModelConfig, params: Params, x: jnp.ndarray,
     if n_periods:
         body = _remat_wrap(period_body, remat)
         scan_cache = cache["scan"] if cache is not None else None
-        (x, aux_total), updated = jax.lax.scan(
-            body, (x, aux_total),
-            (params["scan"], scan_cache))
+        with jax.named_scope(scopes.LAYERS):
+            (x, aux_total), updated = jax.lax.scan(
+                body, (x, aux_total),
+                (params["scan"], scan_cache))
         if cache is not None:
             new_cache["scan"] = updated
     if n_tail:
@@ -242,10 +254,11 @@ def forward_blocks(cfg: ModelConfig, params: Params, x: jnp.ndarray,
         for i in range(n_tail):
             kind = cfg.block_pattern[i % period]
             c_i = cache["tail"][i] if cache is not None else None
-            x, nc, a = _apply_layer(cfg, kind, params["tail"][i], x,
-                                    positions, c_i, cache_len, use_kernels,
-                                    moe_mode)
-            aux_total = aux_total + a
+            with jax.named_scope(scopes.LAYERS):
+                x, nc, a = _apply_layer(cfg, kind, params["tail"][i], x,
+                                        positions, c_i, cache_len,
+                                        use_kernels, moe_mode)
+                aux_total = aux_total + a
             tail_caches.append(nc)
         if cache is not None:
             new_cache["tail"] = tail_caches
@@ -255,14 +268,15 @@ def forward_blocks(cfg: ModelConfig, params: Params, x: jnp.ndarray,
 def embed_inputs(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
                  dtype=jnp.bfloat16) -> jnp.ndarray:
     """tokens/features -> [B, S, d] stream."""
-    if cfg.frontend is not None and cfg.frontend.kind == "audio":
-        return F.apply_audio_features(
-            cfg, params["frontend"], inputs["features"].astype(dtype))
-    x = params["embed"].astype(dtype)[inputs["tokens"]]
-    if cfg.frontend is not None and cfg.frontend.kind == "vision":
-        x = F.apply_vision_prefix(cfg, params["frontend"], x,
-                                  inputs["vision_embeds"])
-    return x
+    with jax.named_scope(scopes.EMBED):
+        if cfg.frontend is not None and cfg.frontend.kind == "audio":
+            return F.apply_audio_features(
+                cfg, params["frontend"], inputs["features"].astype(dtype))
+        x = params["embed"].astype(dtype)[inputs["tokens"]]
+        if cfg.frontend is not None and cfg.frontend.kind == "vision":
+            x = F.apply_vision_prefix(cfg, params["frontend"], x,
+                                      inputs["vision_embeds"])
+        return x
 
 
 def positions_for(cfg: ModelConfig, batch: int, seq: int,
@@ -326,8 +340,9 @@ def train_loss(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
     positions = positions_for(cfg, Bsz, S)
     x, _, aux = forward_blocks(cfg, params, x, positions, None,
                                use_kernels, moe_mode, remat)
-    x = B.apply_norm(cfg, params["final_norm"], x)
-    loss = chunked_xent(cfg, params, x, inputs["labels"])
+    with jax.named_scope(scopes.HEAD):
+        x = B.apply_norm(cfg, params["final_norm"], x)
+        loss = chunked_xent(cfg, params, x, inputs["labels"])
     if cfg.moe is not None:
         loss = loss + cfg.moe.load_balance_loss_weight * aux / cfg.num_layers
     return loss, {"aux_loss": aux}
@@ -346,8 +361,9 @@ def prefill(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
     x, new_cache, _ = forward_blocks(cfg, params, x, positions,
                                      cache if cfg.is_decoder else None,
                                      use_kernels, moe_mode)
-    x = B.apply_norm(cfg, params["final_norm"], x)
-    logits = _head_logits(cfg, params, x[:, -1:])
+    with jax.named_scope(scopes.HEAD):
+        x = B.apply_norm(cfg, params["final_norm"], x)
+        logits = _head_logits(cfg, params, x[:, -1:])
     if new_cache is not None:
         new_cache["len"] = cache["len"] + S
     return logits, new_cache
@@ -359,12 +375,14 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                 dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """One decode step: tokens [B, 1] + cache -> logits [B, 1, V] + cache."""
     params = cast_params_for_compute(params, dtype)
-    x = params["embed"][tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["embed"][tokens]
     Bsz = x.shape[0]
     positions = positions_for(cfg, Bsz, 1, offset=cache["len"])
     x, new_cache, _ = forward_blocks(cfg, params, x, positions, cache,
                                      use_kernels, moe_mode)
-    x = B.apply_norm(cfg, params["final_norm"], x)
-    logits = _head_logits(cfg, params, x)
+    with jax.named_scope(scopes.HEAD):
+        x = B.apply_norm(cfg, params["final_norm"], x)
+        logits = _head_logits(cfg, params, x)
     new_cache["len"] = cache["len"] + 1
     return logits, new_cache
