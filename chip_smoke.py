@@ -130,8 +130,8 @@ def kernel_phase(seed: int, vocab: int, d: int, rows: int, seq: int) -> None:
     for hq, hkv, hd in ((16, 2, 128), (16, 8, 64), (64, 8, 128)):
         ks = jax.random.split(jax.random.PRNGKey(seed + hq + hd), 4)
         q = jax.random.normal(ks[0], (8, hq, hd), jnp.bfloat16)
-        kc = jax.random.normal(ks[1], (8, seq, hkv, hd), jnp.bfloat16)
-        vc = jax.random.normal(ks[2], (8, seq, hkv, hd), jnp.bfloat16)
+        kc = jax.random.normal(ks[1], (8, seq, hkv * hd), jnp.bfloat16)
+        vc = jax.random.normal(ks[2], (8, seq, hkv * hd), jnp.bfloat16)
         lens = jax.random.randint(ks[3], (8,), 1, seq + 1)
         with jax.default_matmul_precision("highest"):
             r = ref.paged_attention_ref(q, kc, vc, lens)

@@ -110,7 +110,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, lengths: jnp.ndarray,
                     page: int = 512) -> jnp.ndarray:
-    """Decode attention. q: [B, Hq, D]; caches [B, T, Hkv, D]; lengths [B]."""
+    """Decode attention. q: [B, Hq, D]; caches [B, T, Hkv * D] (the model's
+    cache layout, read as it stands); lengths [B]."""
     with jax.named_scope(scopes.PAGED_ATTENTION):
         kp, _ = _pad_to(k_cache, 1, page)
         vp, _ = _pad_to(v_cache, 1, page)

@@ -8,13 +8,14 @@ grid pipeline keeps multiple page DMAs in flight while the MXU consumes the
 previous page (issue/complete decoupling). Pages past the sequence length
 are skipped via the scalar-prefetched `lengths`.
 
-Layout: the cache is read as ``[B, T, W]`` with ``W = Hkv * D`` (a reshape
-of ``[B, T, Hkv, D]``), so a page block ``(1, page, W)`` spans every KV head
-and meets the TPU's (8, 128) block rule for any head_dim. Queries come as
-``[B, Hkv, G, D]``, the ``G = Hq / Hkv`` query heads of each KV head. One
-grid step walks the KV heads: head ``h`` scores its ``[G, D]`` queries
-against the page's lanes ``[h*D, (h+1)*D)``, so each matmul is as wide as
-one head, not the whole page.
+Layout: the cache is ``[B, T, W]`` with ``W = Hkv * D``, each position's KV
+heads side by side in one row, as the model stores it (`lm.init_cache`),
+and the kernel reads it as it stands. A page block ``(1, page, W)`` spans
+every KV head and meets the TPU's (8, 128) block rule for any head_dim.
+Queries come as ``[B, Hkv, G, D]``, the ``G = Hq / Hkv`` query heads of
+each KV head. One grid step walks the KV heads: head ``h`` scores its
+``[G, D]`` queries against the page's lanes ``[h*D, (h+1)*D)``, so each
+matmul is as wide as one head, not the whole page.
 """
 from __future__ import annotations
 
@@ -25,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro import scopes
 
 NEG_INF = -1e30
 
@@ -76,20 +75,20 @@ def _paged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, lengths: jnp.ndarray,
                     page: int = 512, interpret: bool = False) -> jnp.ndarray:
-    """q: [B, Hq, D]; k_cache/v_cache: [B, T, Hkv, D]; lengths: [B] ->
-    out [B, Hq, D]. Query head i reads KV head i // (Hq / Hkv)."""
+    """q: [B, Hq, D]; k_cache/v_cache: [B, T, Hkv * D]; lengths: [B] ->
+    out [B, Hq, D]. Query head i reads KV head h = i // (Hq / Hkv): the
+    lanes [h * D, (h + 1) * D) of each cache row."""
     B, Hq, D = q.shape
-    T, Hkv = k_cache.shape[1], k_cache.shape[2]
-    G, W = Hq // Hkv, Hkv * D
+    T, W = k_cache.shape[1], k_cache.shape[2]
+    Hkv = W // D
+    assert Hkv * D == W and Hq % Hkv == 0, (Hq, W, D)
+    G = Hq // Hkv
     page = min(page, T)
     assert T % page == 0, (T, page)
     scale = 1.0 / math.sqrt(D)
     kernel = functools.partial(_paged_kernel, page=page, scale=scale)
     heads = pl.BlockSpec((1, Hkv, G, D), lambda b, pi, L: (b, 0, 0, 0))
     q_heads = q.reshape(B, Hkv, G, D)
-    with jax.named_scope(scopes.KV_RELAYOUT):   # [B, T, Hkv, D] -> [B, T, W]
-        k_pages = k_cache.reshape(B, T, W)
-        v_pages = v_cache.reshape(B, T, W)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -109,5 +108,5 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(lengths, q_heads, k_pages, v_pages)
+    )(lengths, q_heads, k_cache, v_cache)
     return out.reshape(B, Hq, D)

@@ -62,10 +62,12 @@ def paged_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
                         v_cache: jnp.ndarray,
                         lengths: jnp.ndarray) -> jnp.ndarray:
     """paged_attention (decode) oracle.
-    q: [B, Hq, D]; caches: [B, T, Hkv, D]; lengths: [B] valid prefix."""
+    q: [B, Hq, D]; caches: [B, T, Hkv * D]; lengths: [B] valid prefix."""
     B, Hq, D = q.shape
-    T, Hkv = k_cache.shape[1], k_cache.shape[2]
-    rep = Hq // Hkv
+    T = k_cache.shape[1]
+    k_cache = k_cache.reshape(B, T, -1, D)     # [B, T, Hkv, D]
+    v_cache = v_cache.reshape(B, T, -1, D)
+    rep = Hq // k_cache.shape[2]
     k = jnp.repeat(k_cache, rep, axis=2)       # [B, T, Hq, D]
     v = jnp.repeat(v_cache, rep, axis=2)
     logits = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),

@@ -194,8 +194,10 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
               return_kv: bool = False) -> Tuple[jnp.ndarray, Optional[Tuple]]:
     """GQA attention. x: [B, S, d].
 
-    Training/prefill: kv_cache is None -> self attention over x.
-    Decode: kv_cache = (k, v) with [B, T, Hkv, D]; x is the new token(s);
+    Training/prefill: kv_cache is None -> self attention over x; with
+    `return_kv` the new_cache is this call's (k, v) as [B, S, Hkv * D].
+    Decode: kv_cache = (k, v) with [B, T, Hkv * D] (`lm.init_cache`'s
+    layout, each position's KV heads side by side); x is the new token(s);
     `cache_len` [B] gives the valid prefix length. Returns (out, new_cache).
     """
     B, S, d = x.shape
@@ -248,20 +250,21 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
 
     new_cache = None
     if kv_cache is not None:
-        ck, cv = kv_cache                              # [B, T, Hkv, D]
+        ck, cv = kv_cache                              # [B, T, Hkv * D]
         T = ck.shape[1]
         # scatter the new tokens at cache_len (decode: S == 1 typically)
         idx = (cache_len[:, None] + jnp.arange(S)[None, :])  # [B, S]
         with jax.named_scope(scopes.KV_CACHE_WRITE):
             bidx = jnp.arange(B)[:, None]
-            ck = ck.at[bidx, idx].set(k.astype(ck.dtype))
-            cv = cv.at[bidx, idx].set(v.astype(cv.dtype))
+            ck = ck.at[bidx, idx].set(_flat_heads(k).astype(ck.dtype))
+            cv = cv.at[bidx, idx].set(_flat_heads(v).astype(cv.dtype))
         new_cache = (ck, cv)
         if use_kernels and S == 1 and window == 0:
             from repro.kernels import ops as kops
             out = kops.paged_attention(q[:, 0], ck, cv, cache_len + S)
             return _out_proj(p, out[:, None]), new_cache
-        k_all, v_all = ck, cv
+        k_all = ck.reshape(B, T, Hkv, hd)
+        v_all = cv.reshape(B, T, Hkv, hd)
         # valid-key mask (+ causal within the new tokens + window)
         k_pos = jnp.arange(T)[None, None, :]                   # [1,1,T]
         q_pos = idx[:, :, None]                                # [B,S,1]
@@ -273,7 +276,7 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         k_all, v_all = k, v
         T = S
         if return_kv:
-            new_cache = (k, v)
+            new_cache = (_flat_heads(k), _flat_heads(v))
         if use_kernels and cfg.causal and S >= 128:
             from repro.kernels import ops as kops
             out = kops.flash_attention(q, k, v, causal=True, window=window)
@@ -304,10 +307,15 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     return _out_proj(p, out), new_cache
 
 
+def _flat_heads(x: jnp.ndarray) -> jnp.ndarray:
+    """Heads [B, S, H, D] -> [B, S, H * D], the KV cache's layout."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
 def _out_proj(p: Params, out: jnp.ndarray) -> jnp.ndarray:
     """Heads [B, S, H, D] -> [B, S, d] through `wo`."""
     with jax.named_scope(scopes.OUT_PROJ):
-        return out.reshape(out.shape[:2] + (-1,)) @ p["wo"]
+        return _flat_heads(out) @ p["wo"]
 
 
 def ring_attention_step(cfg: ModelConfig, p: Params, x: jnp.ndarray,
@@ -316,8 +324,9 @@ def ring_attention_step(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                         ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Sliding-window decode with a ring-buffered KV cache.
 
-    x: [B, 1, d]; ck/cv: [B, W, Hkv, D] hold the last W tokens' K/V (already
-    roped at their absolute positions); cache_len: [B] tokens seen so far.
+    x: [B, 1, d]; ck/cv: [B, W, Hkv * D] hold the last W tokens' K/V
+    (already roped at their absolute positions); cache_len: [B] tokens seen
+    so far.
     """
     Bsz, S, d = x.shape
     assert S == 1
@@ -334,16 +343,15 @@ def ring_attention_step(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                        cfg.mrope_sections)
         k = apply_rope(k.reshape(Bsz, 1, Hkv, hd), positions, cfg.rope_theta,
                        cfg.mrope_sections)
-        v = v.reshape(Bsz, 1, Hkv, hd)
     with jax.named_scope(scopes.KV_CACHE_WRITE):
         slot = cache_len % Wn                               # [B]
         bidx = jnp.arange(Bsz)
-        ck = ck.at[bidx, slot].set(k[:, 0].astype(ck.dtype))
+        ck = ck.at[bidx, slot].set(_flat_heads(k)[:, 0].astype(ck.dtype))
         cv = cv.at[bidx, slot].set(v[:, 0].astype(cv.dtype))
     valid = jnp.arange(Wn)[None, :] <= jnp.minimum(cache_len, Wn - 1)[:, None]
     rep = Hq // Hkv
-    k_all = jnp.repeat(ck, rep, axis=2)
-    v_all = jnp.repeat(cv, rep, axis=2)
+    k_all = jnp.repeat(ck.reshape(Bsz, Wn, Hkv, hd), rep, axis=2)
+    v_all = jnp.repeat(cv.reshape(Bsz, Wn, Hkv, hd), rep, axis=2)
     logits = jnp.einsum("bshd,bthd->bhst", q, k_all) / math.sqrt(hd)
     logits = jnp.where(valid[:, None, None, :], logits.astype(jnp.float32),
                        -1e30)

@@ -91,14 +91,17 @@ def init_model(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
 # =================================================================== caches
 def init_layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                      dtype=jnp.bfloat16) -> Params:
-    hd, hkv = cfg.resolved_head_dim, cfg.num_kv_heads
+    """Attention K and V are [batch, len, Hkv * D], each position's KV heads
+    side by side: the layout the paged kernel reads, so decode never relays
+    the cache."""
+    width = cfg.num_kv_heads * cfg.resolved_head_dim
     if kind == BLOCK_FULL:
-        return {"k": jnp.zeros((batch, max_len, hkv, hd), dtype),
-                "v": jnp.zeros((batch, max_len, hkv, hd), dtype)}
+        return {"k": jnp.zeros((batch, max_len, width), dtype),
+                "v": jnp.zeros((batch, max_len, width), dtype)}
     if kind == BLOCK_LOCAL:
         w = min(cfg.window_size or max_len, max_len)
-        return {"k": jnp.zeros((batch, w, hkv, hd), dtype),
-                "v": jnp.zeros((batch, w, hkv, hd), dtype)}
+        return {"k": jnp.zeros((batch, w, width), dtype),
+                "v": jnp.zeros((batch, w, width), dtype)}
     if kind == BLOCK_RGLRU:
         return R.init_rglru_state(cfg, batch)
     if kind == BLOCK_RWKV6:

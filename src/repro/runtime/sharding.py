@@ -181,13 +181,15 @@ def cache_shardings(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
         off = 1 if "'scan'" in path else 0
         axes = [None] * nd
         if "'k'" in path or "'v'" in path:
-            b_i, t_i, h_i = off, off + 1, off + 2
+            # [B, T, Hkv * D]: the last axis splits on whole KV heads only
+            b_i, t_i, w_i = off, off + 1, off + 2
+            heads_split = cfg.num_kv_heads % msize == 0
             if shape[b_i] % dsize == 0:
                 axes[b_i] = dp_spec
-            if shape[t_i] % msize == 0 and shape[h_i] % msize != 0:
+            if shape[t_i] % msize == 0 and not heads_split:
                 axes[t_i] = "model"
-            elif shape[h_i] % msize == 0:
-                axes[h_i] = "model"
+            elif heads_split:
+                axes[w_i] = "model"
             return P(*axes)
         if "'s'" in path:      # rwkv6 state [P, B, H, N, N]
             if shape[off] % dsize == 0:

@@ -10,7 +10,6 @@ nothing else. The path reads from the outside in, for example in
     jit(serve_step)/layers/while/body/dynamic_update_slice   stacking its ys
     jit(serve_step)/layers/while/body/closed_call/attn/qkv/dot_general
     .../attn/kv_cache_write/scatter                 the new K/V into the cache
-    .../attn/paged_attention/jit(paged_attention)/kv_relayout/reshape
     .../attn/paged_attention/jit(paged_attention)/pallas_call
     .../attn/out_proj/dot_general
     jit(serve_step)/layers/while/body/closed_call/mlp/dot_general
@@ -37,6 +36,10 @@ ATTN, RGLRU, RWKV6 = "attn", "rglru", "rwkv6"
 MLP, MOE = "mlp", "moe"
 QKV, KV_CACHE_WRITE, OUT_PROJ = "qkv", "kv_cache_write", "out_proj"
 FLASH_ATTENTION, PAGED_ATTENTION = "flash_attention", "paged_attention"
+# No program op lies under `kv_relayout` since the cache is stored in the
+# paged kernel's layout. The name stays only because the chip benchmark's
+# scope readers (`benchmarks/chip/scopes.py`) import it; the next change to
+# those readers may drop it.
 KV_RELAYOUT = "kv_relayout"
 
 # the mixer half's scope for each block kind (`repro.models.lm.BLOCK_*`)

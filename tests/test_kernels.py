@@ -137,8 +137,8 @@ def test_flash_attention_bf16():
 ])
 def test_paged_attention(b, hq, hkv, t, d, page):
     q = jnp.array(RNG.standard_normal((b, hq, d)), jnp.float32) * 0.3
-    kc = jnp.array(RNG.standard_normal((b, t, hkv, d)), jnp.float32) * 0.3
-    vc = jnp.array(RNG.standard_normal((b, t, hkv, d)), jnp.float32)
+    kc = jnp.array(RNG.standard_normal((b, t, hkv * d)), jnp.float32) * 0.3
+    vc = jnp.array(RNG.standard_normal((b, t, hkv * d)), jnp.float32)
     lens = jnp.array(RNG.integers(1, t + 1, b), jnp.int32)
     out = paged_attention(q, kc, vc, lens, page=page, interpret=True)
     expect = ref.paged_attention_ref(q, kc, vc, lens)

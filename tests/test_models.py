@@ -77,6 +77,41 @@ def test_prefill_decode_consistency(arch):
         np.asarray(logits_ref[:, -1], np.float32), atol=0.15, rtol=0.05)
 
 
+@pytest.mark.parametrize("arch,use_kernels", [
+    ("qwen2.5-3b", True),           # the paged kernel, interpreted
+    ("qwen2.5-3b", False),          # the masked jnp decode
+    ("recurrentgemma-9b", False),   # the ring of its `local` layers
+], ids=["qwen2.5-3b-kernels", "qwen2.5-3b", "recurrentgemma-9b"])
+def test_kv_cache_flat_over_heads_and_decode_matches_prefill(arch,
+                                                             use_kernels):
+    """Attention caches are [B, T, Hkv * D], the layout the paged kernel
+    reads; one decode step through them after a prefill gives the logits
+    of a prefill one token longer."""
+    cfg = configs.get_smoke_config(arch)
+    params = lm.init_model(cfg, jax.random.PRNGKey(2))
+    B, S, T = 2, 20, 24          # S past recurrentgemma's window of 16
+    f32 = jnp.float32
+    cache = lm.init_cache(cfg, B, T, dtype=f32)
+    width = cfg.num_kv_heads * cfg.resolved_head_dim
+    lens = {T, min(cfg.window_size or T, T)}
+    kv = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+          if jax.tree_util.keystr(path).endswith(("['k']", "['v']"))]
+    assert kv and all(leaf.shape[-3] == B and leaf.shape[-2] in lens
+                      and leaf.shape[-1] == width for leaf in kv)
+
+    tokens = make_inputs(cfg, B, S + 1, seed=4)["tokens"]
+    logits, cache = lm.prefill(cfg, params, {"tokens": tokens[:, :S]}, cache,
+                               use_kernels=use_kernels, dtype=f32)
+    logits_d, _ = lm.decode_step(cfg, params, tokens[:, S:], cache,
+                                 use_kernels=use_kernels, dtype=f32)
+    logits_ref, _ = lm.prefill(cfg, params, {"tokens": tokens},
+                               lm.init_cache(cfg, B, T, dtype=f32),
+                               dtype=f32)
+    np.testing.assert_allclose(np.asarray(logits_d[:, -1]),
+                               np.asarray(logits_ref[:, -1]),
+                               atol=1e-4, rtol=1e-4)
+
+
 def test_mrope_text_equals_rope():
     """For pure text (three equal position streams), M-RoPE == RoPE."""
     x = jnp.array(np.random.default_rng(0).standard_normal((2, 8, 4, 16)),

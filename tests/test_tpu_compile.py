@@ -69,7 +69,8 @@ def _compile(fn, *specs) -> str:
 def test_paged_attention_compiles(one_chip, hq, hkv, d):
     B, T = 8, 1024
     q = jax.ShapeDtypeStruct((B, hq, d), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((B, T, hkv, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, T, hkv * d), jnp.bfloat16,
+                              sharding=one_chip)
     lens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
     _compile(lambda q, k, v, n: paged_attention(q, k, v, n, page=512),
              q, kv, kv, lens)
@@ -122,8 +123,11 @@ def test_stream_triad_compiles(one_chip, dtype):
 L, B, T, S = 36, 32, 1024, 512
 FF, HQ, HKV, HD = 11008, 16, 2, 128
 TABLE = f"bf16[{VOCAB},{D_MODEL}]"
-STACKED_CACHE = f"bf16[{L},{B},{T},{HKV},{HD}]"
-PAGED_CACHE = f"bf16[{B},{T},{HKV * HD}]"
+STACKED_CACHE = f"bf16[{L},{B},{T},{HKV * HD}]"
+PAGED_CACHE = f"bf16[{B},{T},{HKV * HD}]"      # one layer's cache, as stored
+# a layer's cache in either layout: as stored, and split over its KV heads
+LAYER_CACHES = {PAGED_CACHE, f"bf16[{B},{T},{HKV},{HD}]"}
+RELAYOUT = {"reshape", "copy", "transpose"}
 MLP_WEIGHTS = {f"bf16[{L},{D_MODEL},{FF}]", f"bf16[{L},{FF},{D_MODEL}]"}
 MATMUL = {"dot", "convolution"}
 
@@ -154,10 +158,6 @@ ROLES = {
         # the new token's K/V scattered into the layer's cache
         "kv_cache_write": (lambda i: "scatter" in i.ops,
                            P.under(P.KV_CACHE_WRITE)),
-        # the cache relaid into the paged kernel's [B, T, Hkv*D]
-        "kv_relayout": (lambda i: i.opcode == "reshape"
-                        and i.shape == PAGED_CACHE,
-                        P.under(P.KV_RELAYOUT)),
         "paged_attention": (lambda i: i.opcode == "custom-call"
                             and PAGED_CACHE in i.args,
                             rf"/{P.PAGED_ATTENTION}/.*/pallas_call$"),
@@ -167,7 +167,7 @@ ROLES = {
         # the prompt's K/V written into the layer's cache
         "kv_cache_write": (
             lambda i: "dynamic-update-slice" in i.ops
-            and f"bf16[1,{B},{S},{HKV},{HD}]" in i.args,
+            and f"bf16[1,{B},{S},{HKV * HD}]" in i.args,
             P.under(P.KV_CACHE_WRITE)),
         "flash_attention": (lambda i: i.opcode == "custom-call"
                             and f"bf16[{B},{HQ},{S},{HD}]" in i.args,
@@ -194,8 +194,9 @@ WORK = {"dot", "convolution", "custom-call", "fusion", "copy", "bitcast",
 
 def _top_level(text: str) -> list:
     """The instructions of the entry computation and of every while
-    loop's body and condition, each with the opcodes it computes (those of
-    its fused computations, for a fusion) and its operands' shapes."""
+    loop's body and condition, each with its name, the opcodes it computes
+    (those of its fused computations, for a fusion), its operands' names
+    and their shapes."""
     insts, entry = P.instructions(text)
     comps = {}
     for name, inst in insts.items():
@@ -210,8 +211,9 @@ def _top_level(text: str) -> list:
 
     top = {entry} | {c for inst in insts.values() if inst.opcode == "while"
                      for c in inst.calls}
-    return [SimpleNamespace(opcode=inst.opcode, shape=inst.shape,
+    return [SimpleNamespace(name=name, opcode=inst.opcode, shape=inst.shape,
                             op_name=inst.op_name, ops=opcodes(name),
+                            operands=inst.operands,
                             args={insts[o].shape for o in inst.operands
                                   if o in insts})
             for name, inst in insts.items() if inst.computation in top]
@@ -273,3 +275,25 @@ def test_step_program_work_lies_under_embed_layers_or_head(step_programs,
                and not re.match(P.outermost(P.EMBED, P.LAYERS, P.HEAD),
                                 i.op_name)}
     assert outside == {f"jit({program})/{n}" for n in OUTSIDE_LAYERS}
+
+
+def test_serve_step_reads_the_cache_where_it_is_stored(step_programs):
+    """The cache is stored in the layout the paged kernel reads: no
+    reshape, copy or transpose (alone or as all of a fusion) takes or makes
+    a layer's cache, and the kernel's cache operands are the outputs of the
+    `kv_cache_write` scatter."""
+    top = {i.name: i for i in _top_level(step_programs["serve_step"])}
+    relaid = [i.name for i in top.values()
+              if (i.opcode in RELAYOUT or i.opcode == "fusion" and i.ops
+                  - {"parameter", "constant", "bitcast"} <= RELAYOUT)
+              and ({i.shape} | i.args) & LAYER_CACHES]
+    assert relaid == []
+    kernels = [i for i in top.values()
+               if i.opcode == "custom-call" and PAGED_CACHE in i.args]
+    assert len(kernels) == 1
+    caches = [top[o] for o in kernels[0].operands
+              if o in top and top[o].shape == PAGED_CACHE]
+    assert len(caches) == 2
+    assert all("scatter" in c.ops
+               and re.search(P.under(P.KV_CACHE_WRITE), c.op_name)
+               for c in caches), [(c.name, c.op_name) for c in caches]
