@@ -82,10 +82,15 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
         if positions.ndim == 3:
             positions = positions[0]
         angles = positions.astype(jnp.float32)[..., None] * freqs  # [B,S,D/2]
-    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)    # [B,S,1,D/2]
-    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
-    x1, x2 = x[..., : D // 2], x[..., D // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    # [x1, x2] -> [x1·cos − x2·sin, x2·cos + x1·sin], written as one
+    # multiply-add with the halves swapped by a rotation within each head:
+    # splitting the head and concatenating the halves instead makes the
+    # compiler lay the q/k projections out head-major, which costs a
+    # transposed copy of wq and wk in every layer at every decode step.
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    cos = jnp.concatenate([cos, cos], -1)[..., None, :].astype(x.dtype)
+    sin = jnp.concatenate([-sin, sin], -1)[..., None, :].astype(x.dtype)
+    return x * cos + jnp.roll(x, D // 2, axis=-1) * sin   # [B,S,H,D]
 
 
 # ---------------------------------------------------------------- attention

@@ -123,6 +123,46 @@ def test_mrope_text_equals_rope():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
+def _rope_half_split(x, positions, theta, sections=()):
+    """RoPE as the rotation of each head's halves: (x1, x2) ->
+    (x1·cos − x2·sin, x2·cos + x1·sin); with M-RoPE sections, each
+    frequency takes its position from the stream its section names."""
+    D = x.shape[-1]
+    freqs = blocks.rope_frequencies(D, theta)
+    if sections:
+        stream = np.repeat(np.arange(len(sections)), sections)    # [D/2]
+        pos = jnp.moveaxis(positions[stream], 0, -1)              # [B,S,D/2]
+    else:
+        pos = positions[..., None]
+    angles = pos.astype(jnp.float32) * freqs
+    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
+    x1, x2 = x[..., :D // 2], x[..., D // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("D,theta,sections", [
+    (64, 10000.0, ()), (128, 1e6, ()),
+    (64, 10000.0, (8, 12, 12)), (128, 1e6, (16, 24, 24))])
+@pytest.mark.parametrize("S", [1, 9])
+def test_rope_bit_equal_to_half_split(dtype, D, theta, sections, S):
+    """`apply_rope` computes the half-split rotation's products and sums:
+    bit-equal with and without M-RoPE. Both run op by op: under jit a
+    backend may contract a product and a sum into one multiply-add, and
+    which it contracts is its choice for either form."""
+    rng = np.random.default_rng(D + S)
+    B, H = 3, 4
+    x = jnp.asarray(rng.standard_normal((B, S, H, D)), dtype)
+    positions = jnp.asarray(rng.integers(0, 32768, (3, B, S) if sections
+                                         else (B, S)), jnp.int32)
+    want = np.asarray(_rope_half_split(x, positions, theta, sections))
+    got = np.asarray(blocks.apply_rope(x, positions, theta, sections))
+    assert got.dtype == want.dtype
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+
+
 def test_chunked_attention_matches_naive():
     cfg = configs.get_smoke_config("qwen2-7b")
     params = lm.init_model(cfg, jax.random.PRNGKey(0))

@@ -128,6 +128,9 @@ PAGED_CACHE = f"bf16[{B},{T},{HKV * HD}]"      # one layer's cache, as stored
 # a layer's cache in either layout: as stored, and split over its KV heads
 LAYER_CACHES = {PAGED_CACHE, f"bf16[{B},{T},{HKV},{HD}]"}
 RELAYOUT = {"reshape", "copy", "transpose"}
+# the stacked attention weights: wq and wo, wk and wv
+ATTN_WEIGHTS = {f"bf16[{L},{D_MODEL},{HQ * HD}]",
+                f"bf16[{L},{D_MODEL},{HKV * HD}]"}
 MLP_WEIGHTS = {f"bf16[{L},{D_MODEL},{FF}]", f"bf16[{L},{FF},{D_MODEL}]"}
 MATMUL = {"dot", "convolution"}
 
@@ -277,6 +280,12 @@ def test_step_program_work_lies_under_embed_layers_or_head(step_programs,
     assert outside == {f"jit({program})/{n}" for n in OUTSIDE_LAYERS}
 
 
+def _relays(i) -> bool:
+    """A copy, reshape or transpose, alone or as all of a fusion."""
+    return (i.opcode in RELAYOUT or i.opcode == "fusion"
+            and i.ops - {"parameter", "constant", "bitcast"} <= RELAYOUT)
+
+
 def test_serve_step_reads_the_cache_where_it_is_stored(step_programs):
     """The cache is stored in the layout the paged kernel reads: no
     reshape, copy or transpose (alone or as all of a fusion) takes or makes
@@ -284,9 +293,7 @@ def test_serve_step_reads_the_cache_where_it_is_stored(step_programs):
     `kv_cache_write` scatter."""
     top = {i.name: i for i in _top_level(step_programs["serve_step"])}
     relaid = [i.name for i in top.values()
-              if (i.opcode in RELAYOUT or i.opcode == "fusion" and i.ops
-                  - {"parameter", "constant", "bitcast"} <= RELAYOUT)
-              and ({i.shape} | i.args) & LAYER_CACHES]
+              if _relays(i) and ({i.shape} | i.args) & LAYER_CACHES]
     assert relaid == []
     kernels = [i for i in top.values()
                if i.opcode == "custom-call" and PAGED_CACHE in i.args]
@@ -297,3 +304,47 @@ def test_serve_step_reads_the_cache_where_it_is_stored(step_programs):
     assert all("scatter" in c.ops
                and re.search(P.under(P.KV_CACHE_WRITE), c.op_name)
                for c in caches), [(c.name, c.op_name) for c in caches]
+
+
+@pytest.mark.parametrize("program", ["serve_step", "prefill_step"])
+def test_step_program_reads_attention_weights_where_stored(step_programs,
+                                                           program):
+    """Each of a layer's wq, wk, wv and wo is read out of the stacked
+    parameters by its matmul alone: no copy, transpose or stand-alone
+    slice of a layer's attention weight. (Rope written as a split and
+    concatenation of each head's halves made `serve_step` slice wq and wk
+    out of the stack and transpose them, in every layer at every step.)"""
+    top = _top_level(step_programs[program])
+    shapes = {i.name: i.shape for i in top}
+    readers = {}
+    for i in top:
+        if i.opcode == "tuple":         # the layer loop's carry
+            continue
+        for o in i.operands:
+            if shapes.get(o) in ATTN_WEIGHTS:
+                readers.setdefault(o, []).append(i)
+    assert len(readers) == 4
+    assert {o: [(i.name, sorted(i.ops)) for i in r]
+            for o, r in readers.items()
+            if len(r) != 1 or r[0].opcode != "fusion"
+            or not r[0].ops & MATMUL} == {}
+
+
+def test_serve_step_writes_the_cache_rows_qkv_computes(step_programs):
+    """The rows the `kv_cache_write` scatters write (the new token's roped
+    K, and its V) are the outputs of the `qkv` fusions that compute them,
+    and no copy or transpose lies under `qkv`: nothing relays the roped K
+    into the cache's row layout."""
+    top = {i.name: i for i in _top_level(step_programs["serve_step"])}
+    scatters = [i for i in top.values()
+                if "scatter" in i.ops and i.shape == PAGED_CACHE]
+    assert len(scatters) == 2
+    rows = [top[o] for s in scatters for o in s.operands
+            if o in top and top[o].shape == f"bf16[{B},{HKV * HD}]"]
+    assert len(rows) == 2
+    assert [(r.name, r.op_name) for r in rows
+            if _relays(r) or not re.search(P.under(P.QKV), r.op_name)
+            or not r.ops & {"add", "multiply"}] == []
+    assert [i.name for i in top.values()
+            if re.search(P.under(P.QKV), i.op_name) and _relays(i)
+            and i.ops & {"copy", "transpose"}] == []
