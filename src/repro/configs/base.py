@@ -30,8 +30,8 @@ class MoEConfig:
     num_shared_experts: int = 0
     router_jitter: float = 0.0
     load_balance_loss_weight: float = 0.01
-    # capacity factor for dropless-vs-capacity dispatch; the dense-routing path
-    # used for dry-runs ignores it, the dispatch kernel honours it.
+    # each expert takes ceil(T * top_k / num_experts * capacity_factor)
+    # tokens a step and drops the rest; num_experts / top_k drops none
     capacity_factor: float = 1.25
 
 

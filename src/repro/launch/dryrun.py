@@ -46,8 +46,7 @@ _SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\]")
 
 # --------------------------------------------------------------- cell builder
 def build_cell(arch: str, shape_name: str, mesh, par=None,
-               moe_mode: str = "capacity", microbatches: int = 0,
-               params_bf16: bool = False):
+               microbatches: int = 0, params_bf16: bool = False):
     """Returns (lower_fn, arg_specs) for one (arch x shape x mesh) cell."""
     cfg = configs.get_config(arch)
     shape = configs.get_shape(shape_name)
@@ -88,8 +87,7 @@ def build_cell(arch: str, shape_name: str, mesh, par=None,
             opt_sds, o_sh)
         opt_cfg = adamw.AdamWConfig()
         step = steps_mod.make_train_step(cfg, par, opt_cfg,
-                                         use_kernels=False,
-                                         moe_mode=moe_mode)
+                                         use_kernels=False)
         fn = jax.jit(step, out_shardings=(p_sh, o_sh, None),
                      donate_argnums=(0, 1))
         args = (params_sds, opt_sds, batch_sds)
@@ -103,14 +101,13 @@ def build_cell(arch: str, shape_name: str, mesh, par=None,
                 lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                    sharding=sh),
                 cache_sds, c_sh)
-            step = steps_mod.make_prefill_step(cfg, par, moe_mode=moe_mode)
+            step = steps_mod.make_prefill_step(cfg, par)
             fn = jax.jit(step, donate_argnums=(2,))
             args = (params_sds, batch_sds, cache_sds)
         else:
             # encoder-only: full forward, no cache
             def enc_fwd(params, batch):
-                return lm.prefill(cfg, params, batch, None,
-                                  moe_mode=moe_mode)[0]
+                return lm.prefill(cfg, params, batch, None)[0]
             fn = jax.jit(enc_fwd)
             args = (params_sds, batch_sds)
     else:  # decode
@@ -121,21 +118,20 @@ def build_cell(arch: str, shape_name: str, mesh, par=None,
         cache_sds = jax.tree.map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
             cache_sds, c_sh)
-        step = steps_mod.make_serve_step(cfg, par, moe_mode=moe_mode)
+        step = steps_mod.make_serve_step(cfg, par)
         fn = jax.jit(step, donate_argnums=(2,))
         args = (params_sds, batch_sds["tokens"], cache_sds)
     return (fn, args), ""
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
-             moe_mode: str = "capacity",
              microbatches: int = 0,
              params_bf16: bool = False) -> Dict[str, Any]:
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
     hints.set_mesh(mesh)
     t0 = time.time()
-    built, reason = build_cell(arch, shape_name, mesh, moe_mode=moe_mode,
+    built, reason = build_cell(arch, shape_name, mesh,
                                microbatches=microbatches,
                                params_bf16=params_bf16)
     if built is None:
@@ -198,25 +194,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     return result
 
 
-def apply_tuning(tune) -> None:
-    """--tune rwkv.impl=chunked attn.q_chunk=1024 ... (perf iterations)."""
-    from repro.models import blocks as _blocks
-    from repro.models import rwkv6 as _rwkv6
-    from repro.models import moe as _moe
-    from repro.models import lm as _lm
-    targets = {"attn": _blocks.ATTN_CONFIG, "rwkv": _rwkv6.RWKV_CONFIG,
-               "moe": _moe.MOE_CONFIG, "lm": _lm.LM_CONFIG}
-    for item in tune:
-        key, _, val = item.partition("=")
-        group, _, field = key.partition(".")
-        cfgd = targets[group]
-        old = cfgd[field]
-        cfgd[field] = type(old)(int(val) if isinstance(old, int)
-                                else float(val) if isinstance(old, float)
-                                else val)
-        print(f"# tune {group}.{field} = {cfgd[field]}", file=sys.stderr)
-
-
 def main() -> None:
     enable_compile_cache()
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
@@ -226,12 +203,8 @@ def main() -> None:
                     help="shape name or 'all'")
     ap.add_argument("--mesh", default="both",
                     choices=["single", "multi", "both"])
-    ap.add_argument("--moe-mode", default="capacity")
     ap.add_argument("--microbatches", type=int, default=0)
     ap.add_argument("--params-bf16", action="store_true")
-    ap.add_argument("--tune", action="append", default=[],
-                    help="perf knobs, e.g. rwkv.impl=chunked "
-                         "attn.chunk_threshold=4096 moe.sharded=1")
     ap.add_argument("--out", default="",
                     help="append JSONL results here")
     args = ap.parse_args()
@@ -239,14 +212,12 @@ def main() -> None:
     shapes = list(configs.SHAPES) if args.shape == "all" else [args.shape]
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]
-    apply_tuning(args.tune)
     failures = 0
     for arch in archs:
         for shape_name in shapes:
             for mp in meshes:
                 try:
                     res = run_cell(arch, shape_name, mp,
-                                   moe_mode=args.moe_mode,
                                    microbatches=args.microbatches,
                                    params_bf16=args.params_bf16)
                 except Exception as e:  # noqa: BLE001 — report and continue

@@ -123,17 +123,13 @@ def _attn_mask(S: int, T: int, causal: bool, window: int,
     return mask
 
 
-# runtime-tunable attention execution knobs (perf iterations mutate these)
-ATTN_CONFIG = {
-    "chunk_threshold": 8192,   # S >= threshold -> chunked (flash-style) path
-    "q_chunk": 512,
-    "kv_chunk": 1024,
-    "pad_heads": 0,            # pad q heads per KV group to a mesh multiple
-}
+# self-attention over S >= this many tokens takes the chunked jnp path
+CHUNK_THRESHOLD = 8192
 
 
 def _chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                       causal: bool, window: int) -> jnp.ndarray:
+                       causal: bool, window: int, *, q_chunk: int = 512,
+                       kv_chunk: int = 1024) -> jnp.ndarray:
     """Pure-JAX flash attention: double scan over query/key chunks with
     running softmax stats — O(S) memory instead of O(S^2). Lowers on any
     backend (the Pallas kernel is the TPU-optimized twin).
@@ -142,8 +138,8 @@ def _chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """
     B, S, H, D = q.shape
     T = k.shape[1]
-    qc = min(ATTN_CONFIG["q_chunk"], S)
-    kc = min(ATTN_CONFIG["kv_chunk"], T)
+    qc = min(q_chunk, S)
+    kc = min(kv_chunk, T)
     nq, nk = S // qc, T // kc
     scale = 1.0 / math.sqrt(D)
     qs = jnp.moveaxis(q.reshape(B, nq, qc, H, D), 1, 0)     # [nq,B,qc,H,D]
@@ -226,24 +222,6 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     msize = hints.axis_size("model")
     head_ok = msize > 1 and Hq % msize == 0
     kv_ok = msize > 1 and Hkv % msize == 0
-    pad_per_group = 0
-    if (ATTN_CONFIG["pad_heads"] and msize > 1 and not head_ok
-            and kv_cache is None):
-        # pad each KV group's query heads so total q heads divide the mesh:
-        # zero heads cost (pad/group)/(group) extra attention FLOPs but keep
-        # K/V replicated instead of sequence-gathered every layer.
-        group = Hq // Hkv
-        target_group = group
-        while (target_group * Hkv) % msize != 0:
-            target_group += 1
-        pad_per_group = target_group - group
-        if pad_per_group:
-            qg = q.reshape(B, S, Hkv, group, hd)
-            qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, pad_per_group),
-                              (0, 0)))
-            q = qg.reshape(B, S, Hkv * target_group, hd)
-            Hq = q.shape[2]
-            head_ok = Hq % msize == 0
     if head_ok:
         q = hints.constrain(q, dp, None, "model", None)
         k = hints.constrain(k, dp, None, "model" if kv_ok else None, None)
@@ -286,7 +264,7 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
             from repro.kernels import ops as kops
             out = kops.flash_attention(q, k, v, causal=True, window=window)
             return _out_proj(p, out), new_cache
-        if S >= ATTN_CONFIG["chunk_threshold"]:
+        if S >= CHUNK_THRESHOLD:
             rep = Hq // Hkv
             out = _chunked_attention(q, jnp.repeat(k, rep, axis=2),
                                      jnp.repeat(v, rep, axis=2),
@@ -303,12 +281,6 @@ def attention(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     logits = jnp.where(mask, logits.astype(jnp.float32), -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     out = jnp.einsum("bhst,bthd->bshd", probs, v_all)
-    if pad_per_group:
-        group = Hq // Hkv
-        out = out.reshape(B, S, Hkv, group, hd)[
-            :, :, :, :group - pad_per_group]
-        Hq = Hkv * (group - pad_per_group)
-        out = out.reshape(B, S, Hq, hd)
     return _out_proj(p, out), new_cache
 
 

@@ -27,12 +27,8 @@ from repro.models import frontends as F
 from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import rwkv6 as W
-from repro.runtime import hints
 
 Params = Dict[str, Any]
-
-# Execution knobs (perf iterations mutate these)
-LM_CONFIG = {"seq_parallel_residual": 0}   # 1 -> Korthikanti-style SP
 
 
 # ==================================================================== init
@@ -185,15 +181,8 @@ def _apply_mixer(cfg: ModelConfig, kind: str, p: Params, h: jnp.ndarray,
 
 def _apply_layer(cfg: ModelConfig, kind: str, p: Params, x: jnp.ndarray,
                  positions: jnp.ndarray, cache: Optional[Params],
-                 cache_len: Optional[jnp.ndarray], use_kernels: bool,
-                 moe_mode: str) -> Tuple[jnp.ndarray, Optional[Params],
-                                         jnp.ndarray]:
-    if LM_CONFIG["seq_parallel_residual"] and x.shape[1] > 1:
-        # sequence-parallel residual stream: norms/elementwise run with S
-        # sharded over "model"; XLA all-gathers S at the matmul boundaries
-        # and reduce-scatters the outputs (halves activation-collective
-        # volume vs all-reduce and shards the residual/norm memory).
-        x = hints.constrain(x, hints.batch_spec_axes(), "model", None)
+                 cache_len: Optional[jnp.ndarray], use_kernels: bool
+                 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
     with jax.named_scope(scopes.MIXER[kind]):
         h = B.apply_norm(cfg, p["norm1"], x)
         out, new_cache = _apply_mixer(cfg, kind, p["mix"], h, positions,
@@ -203,7 +192,7 @@ def _apply_layer(cfg: ModelConfig, kind: str, p: Params, x: jnp.ndarray,
     with jax.named_scope(scopes.MLP if cfg.moe is None else scopes.MOE):
         h2 = B.apply_norm(cfg, p["norm2"], x)
         if cfg.moe is not None:
-            ffn_out, aux = M.apply_moe(cfg, p["ffn"], h2, mode=moe_mode)
+            ffn_out, aux = M.apply_moe_capacity(cfg, p["ffn"], h2)
         else:
             ffn_out = B.apply_mlp(cfg, p["ffn"], h2)
         return x + ffn_out, new_cache, aux
@@ -221,8 +210,7 @@ def _remat_wrap(fn, remat: str):
 
 def forward_blocks(cfg: ModelConfig, params: Params, x: jnp.ndarray,
                    positions: jnp.ndarray, cache: Optional[Params] = None,
-                   use_kernels: bool = False, moe_mode: str = "capacity",
-                   remat: str = "none"
+                   use_kernels: bool = False, remat: str = "none"
                    ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
     period = len(cfg.block_pattern)
     n_periods, n_tail = divmod(cfg.num_layers, period)
@@ -237,7 +225,7 @@ def forward_blocks(cfg: ModelConfig, params: Params, x: jnp.ndarray,
         for j, kind in enumerate(cfg.block_pattern):
             c_j = None if pcache is None else pcache[j]
             xc, nc, a = _apply_layer(cfg, kind, pparams[j], xc, positions,
-                                     c_j, cache_len, use_kernels, moe_mode)
+                                     c_j, cache_len, use_kernels)
             ncaches.append(nc)
             aux = aux + a
         out_caches = tuple(ncaches) if pcache is not None else None
@@ -260,7 +248,7 @@ def forward_blocks(cfg: ModelConfig, params: Params, x: jnp.ndarray,
             with jax.named_scope(scopes.LAYERS):
                 x, nc, a = _apply_layer(cfg, kind, params["tail"][i], x,
                                         positions, c_i, cache_len,
-                                        use_kernels, moe_mode)
+                                        use_kernels)
                 aux_total = aux_total + a
             tail_caches.append(nc)
         if cache is not None:
@@ -333,8 +321,7 @@ def cast_params_for_compute(params: Params, dtype=jnp.bfloat16) -> Params:
 
 # ============================================================== entrypoints
 def train_loss(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
-               use_kernels: bool = False, moe_mode: str = "capacity",
-               remat: str = "selective",
+               use_kernels: bool = False, remat: str = "selective",
                dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Full-sequence LM (or masked-frame) loss."""
     params = cast_params_for_compute(params, dtype)
@@ -342,7 +329,7 @@ def train_loss(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
     Bsz, S = x.shape[:2]
     positions = positions_for(cfg, Bsz, S)
     x, _, aux = forward_blocks(cfg, params, x, positions, None,
-                               use_kernels, moe_mode, remat)
+                               use_kernels, remat)
     with jax.named_scope(scopes.HEAD):
         x = B.apply_norm(cfg, params["final_norm"], x)
         loss = chunked_xent(cfg, params, x, inputs["labels"])
@@ -353,7 +340,6 @@ def train_loss(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
 
 def prefill(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
             cache: Params, use_kernels: bool = False,
-            moe_mode: str = "capacity",
             dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Encoder forward / decoder prefill: returns last-position logits and a
     filled cache (for decoders)."""
@@ -363,7 +349,7 @@ def prefill(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
     positions = positions_for(cfg, Bsz, S)
     x, new_cache, _ = forward_blocks(cfg, params, x, positions,
                                      cache if cfg.is_decoder else None,
-                                     use_kernels, moe_mode)
+                                     use_kernels)
     with jax.named_scope(scopes.HEAD):
         x = B.apply_norm(cfg, params["final_norm"], x)
         logits = _head_logits(cfg, params, x[:, -1:])
@@ -374,7 +360,6 @@ def prefill(cfg: ModelConfig, params: Params, inputs: Dict[str, Any],
 
 def decode_step(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, use_kernels: bool = False,
-                moe_mode: str = "capacity",
                 dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """One decode step: tokens [B, 1] + cache -> logits [B, 1, V] + cache."""
     params = cast_params_for_compute(params, dtype)
@@ -383,7 +368,7 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     Bsz = x.shape[0]
     positions = positions_for(cfg, Bsz, 1, offset=cache["len"])
     x, new_cache, _ = forward_blocks(cfg, params, x, positions, cache,
-                                     use_kernels, moe_mode)
+                                     use_kernels)
     with jax.named_scope(scopes.HEAD):
         x = B.apply_norm(cfg, params["final_norm"], x)
         logits = _head_logits(cfg, params, x)

@@ -1,15 +1,9 @@
 """Mixture-of-Experts FFN (kimi-k2: 384e top-8 + 1 shared; granite: 32e top-8).
 
-Two execution paths:
-
-* **dense-routing einsum** (default; used for dry-run lowering): every token
-  multiplies a [E, d, ff] stacked weight through a dispatch one-hot — the
-  compiled HLO keeps the expert dimension intact so expert-parallel sharding
-  (experts over the "model" axis, all-to-all dispatch) is visible to SPMD.
-* **gathered path** (`capacity` mode): tokens are sorted by expert and run
-  through per-expert matmuls at a capacity bound — this is what the AMU-style
-  async expert streaming optimizes (experts are "far"; only the active top-k
-  groups are fetched).
+The model runs the **capacity dispatch** (`apply_moe_capacity`): tokens are
+sorted by expert and run through per-expert matmuls at a capacity bound.
+`apply_moe_dense` multiplies every token by every expert through a dispatch
+one-hot; it drops nothing, and tests compare the capacity dispatch with it.
 
 The router adds the standard auxiliary load-balancing loss.
 """
@@ -25,9 +19,6 @@ from repro.configs.base import ModelConfig
 from repro.models.blocks import _dense_init
 
 Params = Dict[str, Any]
-
-# Execution knobs (perf iterations mutate these)
-MOE_CONFIG = {"sharded": 0}   # 1 -> shard_map local-capacity dispatch
 
 
 def init_moe(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
@@ -73,11 +64,10 @@ def route(cfg: ModelConfig, p: Params,
 
 def apply_moe_dense(cfg: ModelConfig, p: Params,
                     x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Dense-dispatch path. x: [B, S, d] -> ([B, S, d], aux_loss).
+    """Dense-dispatch reference. x: [B, S, d] -> ([B, S, d], aux_loss).
 
-    Dispatch/combine are einsums against a [T, k, E] one-hot; XLA SPMD turns
-    the expert dimension contraction into all-to-alls when experts are
-    sharded over the "model" axis.
+    Dispatch/combine are einsums against a [T, k, E] one-hot, so no token
+    is dropped.
     """
     m = cfg.moe
     B, S, d = x.shape
@@ -103,11 +93,12 @@ def apply_moe_dense(cfg: ModelConfig, p: Params,
 
 def apply_moe_capacity(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Capacity-bounded gathered path: tokens sorted by expert, per-expert
-    matmuls at capacity C = ceil(T * k / E * capacity_factor). Overflowing
-    tokens are dropped (standard Switch-style), making FLOPs proportional to
-    *active* params — this is the path the async expert-streaming runtime
-    feeds one expert group at a time."""
+    """Capacity-bounded dispatch. x: [B, S, d] -> ([B, S, d], aux_loss).
+
+    Tokens are sorted by expert into per-expert matmuls at capacity
+    C = ceil(T * k / E * capacity_factor). Overflowing tokens are dropped
+    (Switch-style), so FLOPs follow the *active* params. A capacity factor
+    of E / k gives C = T, which drops nothing."""
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
@@ -139,99 +130,3 @@ def apply_moe_capacity(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         out = out + (jax.nn.silu(xt @ sp["w_gate"])
                      * (xt @ sp["w_up"])) @ sp["w_down"]
     return out.reshape(B, S, d), aux
-
-
-def apply_moe(cfg: ModelConfig, p: Params, x: jnp.ndarray,
-              mode: str = "dense") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if mode == "capacity":
-        if MOE_CONFIG.get("sharded"):
-            return apply_moe_sharded(cfg, p, x)
-        return apply_moe_capacity(cfg, p, x)
-    return apply_moe_dense(cfg, p, x)
-
-
-def apply_moe_sharded(cfg: ModelConfig, p: Params, x: jnp.ndarray
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Expert-parallel dispatch with LOCAL capacity via shard_map.
-
-    Tokens are batch-sharded over the data axes and replicated over "model";
-    experts are sharded over "model". Each device routes its local tokens,
-    dispatches only to its local expert group at a local capacity bound
-    (buffers scale with tokens/device, not global tokens), runs the expert
-    FFNs, and psums the partial combine over "model" — one all-reduce per
-    layer instead of global-capacity gather/scatter traffic.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from repro.runtime import hints
-
-    mesh = hints.get_mesh()
-    m = cfg.moe
-    E = m.num_experts
-    msize = hints.axis_size("model")
-    if mesh is None or msize <= 1 or E % msize != 0:
-        return apply_moe_capacity(cfg, p, x)
-    dp = hints.batch_spec_axes()
-    E_local = E // msize
-
-    def local_fn(xl, router, wg, wu, wd, shared):
-        Bl, Sl, d = xl.shape
-        Tl = Bl * Sl
-        xt = xl.reshape(Tl, d)
-        logits = (xt.astype(jnp.float32) @ router)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, m.top_k)
-        weights = (weights / jnp.sum(weights, -1, keepdims=True)
-                   ).astype(xl.dtype)
-        one_hot_all = jax.nn.one_hot(experts, E, dtype=jnp.float32)
-        frac = jnp.sum(one_hot_all, axis=(0, 1)) / (Tl * m.top_k)
-        aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
-        # local expert group
-        e0 = jax.lax.axis_index("model") * E_local
-        eloc = experts - e0                                    # [Tl, k]
-        mine = (eloc >= 0) & (eloc < E_local)
-        C = max(1, int(math.ceil(Tl * m.top_k / E * m.capacity_factor)))
-        flat_e = jnp.where(mine, eloc, E_local).reshape(-1)    # [Tl*k]
-        flat_w = (weights * mine).reshape(-1)
-        flat_tok = jnp.repeat(jnp.arange(Tl), m.top_k)
-        oh = jax.nn.one_hot(flat_e, E_local, dtype=jnp.int32)
-        mypos = jnp.take_along_axis(
-            jnp.cumsum(oh, axis=0) - 1,
-            jnp.minimum(flat_e, E_local - 1)[:, None], axis=1)[:, 0]
-        keep = (mypos < C) & (flat_e < E_local)
-        slot = jnp.where(keep, flat_e * C + mypos, E_local * C)
-        buf = jnp.zeros((E_local * C + 1, d), xl.dtype).at[slot].set(
-            xt[flat_tok])
-        grouped = buf[:E_local * C].reshape(E_local, C, d)
-        gate = jnp.einsum("ecd,edf->ecf", grouped, wg)
-        up = jnp.einsum("ecd,edf->ecf", grouped, wu)
-        act = jax.nn.silu(gate) * up
-        eout = jnp.einsum("ecf,efd->ecd", act, wd).reshape(E_local * C, d)
-        eout = jnp.concatenate([eout, jnp.zeros((1, d), xl.dtype)], axis=0)
-        tok_out = eout[slot] * (flat_w * keep)[:, None]
-        out = jnp.zeros((Tl, d), xl.dtype).at[flat_tok].add(tok_out)
-        if m.num_shared_experts:
-            # shared-expert hidden dim is sharded over "model", so its
-            # partial joins the expert partials in ONE psum
-            out = out + (jax.nn.silu(xt @ shared["w_gate"])
-                         * (xt @ shared["w_up"])) @ shared["w_down"]
-        out = jax.lax.psum(out, "model")
-        return out.reshape(Bl, Sl, d), aux
-
-    shared = p.get("shared", {"w_gate": jnp.zeros((cfg.d_model, msize),
-                                                  x.dtype)})
-    dp_spec = dp if len(dp) > 1 else dp[0]
-    has_shared = m.num_shared_experts > 0
-    shared_specs = {"w_gate": P(None, "model"), "w_up": P(None, "model"),
-                    "w_down": P("model", None)} if has_shared else P(None,
-                                                                     None)
-    out, aux = jax.shard_map(
-        local_fn, mesh=mesh,
-        in_specs=(P(dp_spec, None, None), P(None, None),
-                  P("model", None, None), P("model", None, None),
-                  P("model", None, None), shared_specs),
-        out_specs=(P(dp_spec, None, None), P()),
-        check_vma=False,
-    )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-      p.get("shared", shared))
-    return out, aux

@@ -7,8 +7,8 @@ key k, value v and decay w_t (data-dependent, in (0,1)) and bonus u:
     S_t = diag(w_t) S_{t-1} + k_t^T v_t            (state: [N, N])
     o_t = r_t (S_{t-1} + diag(u) k_t^T v_t)
 
-Train/prefill runs a chunked lax.scan over time (state carried between
-chunks -> sub-quadratic, O(S * N^2) work); decode is the single-step update.
+Train/prefill runs a lax.scan over time (linear in S, O(S * N^2) work);
+decode is the same update for one token.
 Token-shift mixing (lerp of x_{t-1}, x_t) uses a 1-token cache in decode.
 
 Simplifications vs the reference implementation (documented): the low-rank
@@ -77,73 +77,9 @@ def _heads(cfg: ModelConfig, t: jnp.ndarray) -> jnp.ndarray:
     return t.reshape(B, S, cfg.num_heads, cfg.resolved_head_dim)
 
 
-# Execution knobs (perf iterations mutate these): "scan" = faithful
-# per-token recurrence; "chunked" = chunk-parallel matmul form (same math,
-# O(S/C) sequential steps, state written once per chunk instead of per
-# token). Safe because our decay parameterization keeps w in [0.95, 1).
-RWKV_CONFIG = {"impl": "scan", "chunk": 64, "mixer_bf16": 0}
-
-
-def rwkv6_chunk_parallel(cfg: ModelConfig, p: Params, r, k, v, w,
-                         state: jnp.ndarray, chunk: int
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Chunk-parallel WKV6: within a chunk of C tokens the recurrence
-    unrolls into two matmuls (an intra-chunk lower-triangular 'attention'
-    and a carried-state term); the state advances once per chunk.
-
-    r/k/v/w: [B, S, H, N] (f32; w in (0,1)); state: [B, H, N, N].
-    Returns (out [B, S, H, N], final state).
-    """
-    B, S, H, N = r.shape
-    C = min(chunk, S)
-    assert S % C == 0, (S, C)
-    u = p["bonus"].reshape(H, N)
-    nc = S // C
-
-    def one_chunk(s0, inp):
-        rc, kc, vc, wc = inp                       # [B, C, H, N]
-        rc, kc, vc = (t.astype(jnp.float32) if t.dtype != jnp.bfloat16
-                      else t for t in (rc, kc, vc))
-        cw = jnp.cumprod(wc, axis=1)               # inclusive decay products
-        cwe = cw / wc                              # exclusive (prod_{s<t})
-        r_dec = (rc.astype(jnp.float32) * cwe).astype(rc.dtype)
-        # carried-state contribution
-        o_state = jnp.einsum("bchn,bhnv->bchv", r_dec,
-                             s0.astype(rc.dtype),
-                             preferred_element_type=jnp.float32)
-        # intra-chunk strictly-causal pair contributions
-        k_scaled = (kc.astype(jnp.float32) / cw).astype(kc.dtype)
-        att = jnp.einsum("bchn,bshn->bhcs", r_dec, k_scaled,
-                         preferred_element_type=jnp.float32)
-        tri = jnp.tril(jnp.ones((C, C), bool), k=-1)
-        att = jnp.where(tri[None, None], att, 0.0)
-        o_intra = jnp.einsum("bhcs,bshv->bchv", att.astype(vc.dtype), vc,
-                             preferred_element_type=jnp.float32)
-        # same-token bonus
-        diag = jnp.einsum("bchn,bchn->bch", rc,
-                          (u[None, None] * kc.astype(jnp.float32)
-                           ).astype(kc.dtype),
-                          preferred_element_type=jnp.float32)
-        o = o_state + o_intra + diag[..., None] * vc
-        # state update: decay the carry, add this chunk's outer products
-        decay_all = cw[:, -1]                      # [B, H, N]
-        k_carry = (kc.astype(jnp.float32)
-                   * (decay_all[:, None] / cw)).astype(kc.dtype)
-        s1 = decay_all[..., None] * s0 + jnp.einsum(
-            "bshn,bshv->bhnv", k_carry, vc,
-            preferred_element_type=jnp.float32)
-        return s1, o
-
-    rs, ks_, vs, ws = (t.reshape(B, nc, C, H, N).swapaxes(0, 1)
-                       for t in (r, k, v, w))
-    s_final, outs = jax.lax.scan(one_chunk, state, (rs, ks_, vs, ws))
-    out = outs.swapaxes(0, 1).reshape(B, S, H, N)
-    return out, s_final
-
-
 def rwkv6_chunk(cfg: ModelConfig, p: Params, r, k, v, w,
                 state: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sequential scan within a chunk. r/k/v/w: [B, S, H, N] (w f32).
+    """Sequential scan over the tokens. r/k/v/w: [B, S, H, N] (w f32).
     state: [B, H, N, N] (f32). Returns (out [B,S,H,N], new state)."""
     u = p["bonus"].reshape(cfg.num_heads, cfg.resolved_head_dim)
 
@@ -166,8 +102,7 @@ def init_rwkv6_state(cfg: ModelConfig, batch: int) -> Dict[str, jnp.ndarray]:
 
 
 def apply_rwkv6(cfg: ModelConfig, p: Params, x: jnp.ndarray,
-                state: Optional[Dict[str, jnp.ndarray]] = None,
-                chunk: int = 256
+                state: Optional[Dict[str, jnp.ndarray]] = None
                 ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
     """RWKV-6 block. x: [B, S, d] -> [B, S, d]."""
     B, S, d = x.shape
@@ -184,15 +119,8 @@ def apply_rwkv6(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     rh, kh, vh = (hints.constrain(t, dp, None, "model", None)
                   for t in (rh, kh, vh))
     wh = hints.constrain(wh, dp, None, "model", None)
-    mix_dtype = (jnp.bfloat16 if RWKV_CONFIG.get("mixer_bf16")
-                 else jnp.float32)
-    rh32, kh32, vh32 = (t.astype(mix_dtype) for t in (rh, kh, vh))
-    if (RWKV_CONFIG["impl"] == "chunked" and S > 1
-            and S % min(RWKV_CONFIG["chunk"], S) == 0):
-        out, s_new = rwkv6_chunk_parallel(cfg, p, rh32, kh32, vh32, wh, s0,
-                                          RWKV_CONFIG["chunk"])
-    else:
-        out, s_new = rwkv6_chunk(cfg, p, rh32, kh32, vh32, wh, s0)
+    rh32, kh32, vh32 = (t.astype(jnp.float32) for t in (rh, kh, vh))
+    out, s_new = rwkv6_chunk(cfg, p, rh32, kh32, vh32, wh, s0)
     out = out.astype(x.dtype).reshape(B, S, d)
     out = (out * g) @ p["w_o"]
     new_state = None

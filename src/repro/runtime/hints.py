@@ -17,17 +17,13 @@ from jax.sharding import PartitionSpec as P
 _state = threading.local()
 
 
-def set_mesh_axes(axes: Optional[Dict[str, int]], mesh=None) -> None:
+def set_mesh_axes(axes: Optional[Dict[str, int]]) -> None:
     _state.axes = dict(axes) if axes else None
-    _state.mesh = mesh
 
 
 def set_mesh(mesh) -> None:
-    set_mesh_axes({k: v for k, v in mesh.shape.items()}, mesh)
-
-
-def get_mesh():
-    return getattr(_state, "mesh", None)
+    """Register `mesh`'s axis sizes."""
+    set_mesh_axes(dict(mesh.shape))
 
 
 def get_mesh_axes() -> Optional[Dict[str, int]]:

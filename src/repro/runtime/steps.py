@@ -20,8 +20,7 @@ Params = Any
 
 def make_train_step(cfg: ModelConfig, par: ParallelConfig,
                     opt_cfg: adamw.AdamWConfig,
-                    use_kernels: bool = False,
-                    moe_mode: str = "capacity") -> Callable:
+                    use_kernels: bool = False) -> Callable:
     """(params, opt_state, batch) -> (params, opt_state, metrics).
 
     par.microbatches > 1 -> gradient accumulation: the global batch is split
@@ -32,8 +31,7 @@ def make_train_step(cfg: ModelConfig, par: ParallelConfig,
 
     def loss_fn(p, mb):
         loss, metrics = lm.train_loss(
-            cfg, p, mb, use_kernels=use_kernels, moe_mode=moe_mode,
-            remat=par.remat)
+            cfg, p, mb, use_kernels=use_kernels, remat=par.remat)
         return loss, metrics
 
     def train_step(params: Params, opt_state: Dict[str, Any],
@@ -70,37 +68,34 @@ def make_train_step(cfg: ModelConfig, par: ParallelConfig,
 
 
 def make_prefill_step(cfg: ModelConfig, par: ParallelConfig,
-                      use_kernels: bool = False,
-                      moe_mode: str = "capacity") -> Callable:
+                      use_kernels: bool = False) -> Callable:
     def prefill_step(params: Params, batch: Dict[str, jnp.ndarray],
                      cache: Params):
         return lm.prefill(cfg, params, batch, cache,
-                          use_kernels=use_kernels, moe_mode=moe_mode)
+                          use_kernels=use_kernels)
     return prefill_step
 
 
 def make_serve_step(cfg: ModelConfig, par: ParallelConfig,
-                    use_kernels: bool = False,
-                    moe_mode: str = "capacity") -> Callable:
+                    use_kernels: bool = False) -> Callable:
     """One decode step: (params, tokens [B,1], cache) -> (logits, cache)."""
     def serve_step(params: Params, tokens: jnp.ndarray, cache: Params):
         return lm.decode_step(cfg, params, tokens, cache,
-                              use_kernels=use_kernels, moe_mode=moe_mode)
+                              use_kernels=use_kernels)
     return serve_step
 
 
 # ------------------------------------------------------------ jit packaging
 def jit_train_step(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
                    opt_cfg: adamw.AdamWConfig, params: Params,
-                   shape: ShapeConfig, use_kernels: bool = False,
-                   moe_mode: str = "capacity"):
+                   shape: ShapeConfig, use_kernels: bool = False):
     """jit with explicit param/opt-state shardings + donation of both.
     `params` may be abstract (jax.eval_shape). Returns (step, param,
     opt-state and batch shardings); batches arrive already placed."""
     p_sh = shd.params_shardings(cfg, par, mesh, params)
     o_sh = shd.opt_state_shardings(cfg, par, mesh, params)
     b_sh = shd.batch_shardings(cfg, par, mesh, shape)
-    step = make_train_step(cfg, par, opt_cfg, use_kernels, moe_mode)
+    step = make_train_step(cfg, par, opt_cfg, use_kernels)
     return jax.jit(
         step,
         in_shardings=(p_sh, o_sh, None),
